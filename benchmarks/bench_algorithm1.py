@@ -12,11 +12,13 @@ from repro.core import CellConfig, ProblemSpec
 from repro.core import algorithm1 as a1
 from repro.core.channel import channel_gains, sample_positions
 from repro.core.online import solve_online
+from repro.launch.cache import enable_compile_cache
 
 from .common import row, save_artifact
 
 
 def main() -> dict:
+    enable_compile_cache()
     cell = CellConfig(num_clients=10)
     spec = ProblemSpec(cell=cell, rho=0.05, num_rounds=20)
     pos = sample_positions(jax.random.PRNGKey(0), cell)

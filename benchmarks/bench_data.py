@@ -39,6 +39,7 @@ from repro.core.selection import RandomScheme
 from repro.data import from_client_datasets, make_mnist_like, shard_noniid
 from repro.data.device import estimate_store_bytes
 from repro.fl import SimConfig, make_runner, stack_round_batches
+from repro.launch.cache import enable_compile_cache
 from repro.models.small import init_mlp, mlp_accuracy, mlp_loss
 
 from .common import write_bench
@@ -158,6 +159,7 @@ def main_quick():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small config for CI smoke")

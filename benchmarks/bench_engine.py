@@ -43,6 +43,7 @@ from repro.core.selection import (AgeBasedScheme, GreedyScheme, ProposedOnline,
 from repro.data import make_mnist_like, shard_noniid
 from repro.fl import (SimConfig, make_runner, run_scenario_matrix,
                       run_seed_matrix, run_simulation_legacy)
+from repro.launch.cache import enable_compile_cache
 from repro.models.small import init_mlp, mlp_accuracy, mlp_loss
 
 from .common import write_bench
@@ -263,6 +264,7 @@ def main_quick():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small config for CI smoke")

@@ -35,6 +35,7 @@ from repro.data import make_mnist_like, shard_noniid
 from repro.data.synthetic import Dataset
 from repro.fl import (FaultConfig, GuardConfig, SimConfig, make_runner,
                       run_fault_matrix)
+from repro.launch.cache import enable_compile_cache
 from repro.models.small import init_mlp, mlp_accuracy, mlp_loss
 
 from .common import write_bench
@@ -163,6 +164,7 @@ def main_quick():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small config for CI smoke")

@@ -13,6 +13,7 @@ from repro.kernels import ref
 from repro.kernels.fl_aggregate import BLOCK_R, LANE, fl_aggregate
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.selective_scan import selective_scan
+from repro.launch.cache import enable_compile_cache
 
 from .common import row, save_artifact
 
@@ -26,6 +27,7 @@ def _time(f, n=3):
 
 
 def main() -> dict:
+    enable_compile_cache()
     out = {}
     key = jax.random.PRNGKey(0)
 

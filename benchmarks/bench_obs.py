@@ -28,6 +28,7 @@ from repro.core import CellConfig
 from repro.core.selection import RandomScheme, participant_bucket
 from repro.fl import SimConfig, make_runner
 from repro.fl.sparse import make_sparse_runner
+from repro.launch.cache import enable_compile_cache
 from repro.models.small import init_mlp, mlp_accuracy, mlp_loss
 from repro.obs import MetricsSpec, metrics_summary
 from repro.obs.telemetry import get_telemetry, timed_compile
@@ -135,6 +136,7 @@ def main_quick():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small config for CI smoke")

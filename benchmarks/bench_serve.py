@@ -34,6 +34,7 @@ import jax
 from repro.core import CellConfig
 from repro.core.channel import channel_gains, sample_positions
 from repro.core.selection import ProblemSpec, online_policy
+from repro.launch.cache import enable_compile_cache
 from repro.obs.telemetry import get_telemetry
 from repro.serve import (AggregationServer, LoadGenConfig, ServeConfig,
                          run_loadgen, toy_world, verify_replay)
@@ -141,6 +142,7 @@ def bench(quick: bool) -> dict:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="CI scale: K=1000, 300 uploads per mode")

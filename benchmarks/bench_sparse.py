@@ -42,6 +42,7 @@ from repro.data.synthetic import Dataset
 from repro.fl import SimConfig, make_runner
 from repro.fl import sparse as sparse_mod
 from repro.fl.sparse import make_sparse_runner
+from repro.launch.cache import enable_compile_cache
 from repro.models.small import init_mlp, mlp_accuracy, mlp_loss
 
 from .common import write_bench
@@ -179,6 +180,7 @@ def main_quick():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small config for CI smoke")

@@ -10,11 +10,13 @@ import numpy as np
 
 from repro.core import ProblemSpec
 from repro.core.selection import ProposedOnline
+from repro.launch.cache import enable_compile_cache
 
 from .common import build_world, row, run_policy, save_artifact
 
 
 def main() -> dict:
+    enable_compile_cache()
     world = build_world(rounds=20, d=2)
     spec = ProblemSpec(cell=world.cell, rho=0.03, num_rounds=world.rounds)
     out = {}

@@ -12,9 +12,11 @@ from repro.core import ProblemSpec
 
 from .common import build_world, row, run_policy, save_artifact
 from repro.core.selection import ProposedOnline
+from repro.launch.cache import enable_compile_cache
 
 
 def main() -> list[dict]:
+    enable_compile_cache()
     # d=2 (strong heterogeneity) exposes the high-ρ drift the paper reports
     world = build_world(d=2, rounds=24)
     rhos = (0.01, 0.03, 0.1, 0.3, 0.9)

@@ -13,6 +13,7 @@ from repro.core.channel import rate_nats
 from repro.core.selection import (AgeBasedScheme, GreedyScheme,
                                   ProposedOnline, RandomScheme,
                                   average_participants, realize)
+from repro.launch.cache import enable_compile_cache
 
 from .common import build_world, row, save_artifact
 
@@ -39,6 +40,7 @@ def expected_energy(world, policy, rounds):
 
 
 def main() -> dict:
+    enable_compile_cache()
     out = {"fig4": [], "fig5": []}
 
     # --- Fig. 4: energy vs avg participants (vary rho) ----------------------

@@ -29,6 +29,7 @@ from repro.data import make_mnist_like, shard_noniid
 from repro.data.device import from_client_datasets
 from repro.fl import AggregatorConfig, SimConfig
 from repro.fl.schemes import SchemeSpec, run_scheme_matrix
+from repro.launch.cache import enable_compile_cache
 
 from .common import FULL, row, save_artifact, write_bench
 
@@ -119,6 +120,7 @@ def run_setting(K: int, rho: float, rounds: int, n_train: int, seeds,
 
 
 def main(argv=None) -> dict:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="tiny CI smoke: short horizon, one seed")

@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.core import CellConfig, ProblemSpec
 from repro.core.channel import sample_positions
+from repro.launch.cache import enable_compile_cache
 
 from .common import build_world, row, run_policy, save_artifact, schemes_matched
 
@@ -58,6 +59,7 @@ def run_scenario(name, near):
 
 
 def main() -> dict:
+    enable_compile_cache()
     out = {"scenario1_near": run_scenario("fig8_s1", near=True),
            "scenario2_far": run_scenario("fig8_s2", near=False)}
     save_artifact("fig8_9_scenarios", out)

@@ -13,6 +13,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.cache import enable_compile_cache
+
 from . import (bench_algorithm1, bench_data, bench_engine, bench_faults,
                bench_kernels, bench_staleness, fig2_3_rho_sweep,
                fig4_5_energy, fig6_7_schemes, fig8_9_scenarios)
@@ -32,6 +34,7 @@ SUITES = [
 
 
 def main() -> None:
+    enable_compile_cache()
     filt = sys.argv[1] if len(sys.argv) > 1 else ""
     print("name,us_per_call,derived")
     failures = []

@@ -35,8 +35,14 @@ def _cluster_classification(key, n, dim, num_classes, noise, hard_frac=0.35):
     manifolds = jax.random.normal(k2, (num_classes, rank, dim)) * 0.6
     y = jax.random.randint(k3, (n,), 0, num_classes)
     coeff = jax.random.normal(k4, (n, rank))
-    base = protos[y] + jnp.einsum("nr,nrd->nd", coeff,
-                                  manifolds[y])
+    # the per-example manifold gather is [n, rank, dim] (10.7 GB at the
+    # paper's 70k MNIST examples), so it is built a chunk of rows at a time
+    chunk = 4096
+    offsets = jnp.concatenate([
+        jnp.einsum("nr,nrd->nd", coeff[i:i + chunk],
+                   manifolds[y[i:i + chunk]])
+        for i in range(0, n, chunk)])
+    base = protos[y] + offsets
     x = base + noise * jax.random.normal(k5, (n, dim))
     return x, y
 

@@ -359,11 +359,13 @@ def init_carry(params: Any, num_clients: int, cfg: SimConfig):
 
 def _make_round_step(vtrain: Callable, loss_fn: Callable, acc_fn: Callable,
                      cfg: SimConfig, cell: CellConfig, num_clients: int,
-                     policy_fn: PolicyFn, hoist: bool):
+                     policy_fn: PolicyFn, hoist: bool,
+                     use_pallas: bool | None = None):
     """The per-round transition shared by every execution mode (full scan
     over pre-stacked batches, in-scan device-store sampling, streaming
     round-chunks): protocol Steps 1-5, fault pipeline, energy ledger,
-    defensive aggregation, strided eval."""
+    defensive aggregation, strided eval.  ``use_pallas`` goes to the
+    aggregation (see :func:`repro.fl.state.masked_aggregate`)."""
     K = num_clients
     faults = cfg.faults
     guards = cfg.guards
@@ -426,14 +428,17 @@ def _make_round_step(vtrain: Callable, loss_fn: Callable, acc_fn: Callable,
             staleness = state.round - state.last_tx
             new_global = scheme_aggregate(
                 state.global_params, deltas, delivered, K, staleness, probs,
-                agg.params() if ap is None else ap, guards=guards)
+                agg.params() if ap is None else ap, guards=guards,
+                use_pallas=use_pallas)
         elif guards is not None and guards.active:
             staleness = state.round - state.last_tx
             new_global = guarded_aggregate(state.global_params, deltas,
-                                           delivered, K, staleness, guards)
+                                           delivered, K, staleness, guards,
+                                           use_pallas=use_pallas)
         else:
             new_global = masked_aggregate(state.global_params, deltas,
-                                          delivered, K)
+                                          delivered, K,
+                                          use_pallas=use_pallas)
         if tapped:
             ap_eff = ((agg.params() if ap is None else ap)
                       if agg is not None else None)
@@ -518,13 +523,18 @@ def build_scan_sim(loss_fn: Callable, acc_fn: Callable, opt: Optimizer,
     hoist = getattr(policy_fn, "state_free", False)
     mesh = _client_mesh(K) if shard_clients in (None, True) else None
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
-        vtrain = shard_map(vtrain, mesh,
-                           in_specs=(P("k"), P("k"), P("k")),
-                           out_specs=P("k"))
+        vtrain = jax.shard_map(vtrain, mesh=mesh,
+                               in_specs=(P("k"), P("k"), P("k")),
+                               out_specs=P("k"))
+    # XLA cannot partition a Pallas kernel over the sharded client axis
+    # ("Mosaic kernels cannot be automatically partitioned"), so a sharded
+    # run aggregates with the jnp path: a per-device fused reduce of its
+    # own rows plus one all-reduce of the model
     round_step = _make_round_step(vtrain, loss_fn, acc_fn, cfg, cell, K,
-                                  policy_fn, hoist)
+                                  policy_fn, hoist,
+                                  use_pallas=False if mesh is not None
+                                  else None)
 
     def hoisted_policy(h_rounds):
         ts = jnp.arange(cfg.rounds, dtype=jnp.int32)
@@ -760,6 +770,11 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
     footprint; see :func:`resolve_data_path`).  On the device path the
     store's client axis is placed on the same mesh as the FL state whenever
     client-axis sharding is active.
+
+    The dense scan runner also carries ``runner.lower(params, h_all,
+    seed=None)`` — its program lowered for that call, for inspecting what
+    was compiled — and ``runner.mesh``, the client-axis mesh (``None`` when
+    unsharded).
     """
     K = len(client_data)
     policy_fn = as_policy_fn(policy)
@@ -794,30 +809,30 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
             from ..launch.sharding import client_axis_shardings
             store = jax.device_put(
                 store, client_axis_shardings(store, sim.mesh, "k"))
-        data_key = data_stream_key(cfg.seed)
-
-        def runner(params, h_all, seed: int | None = None) -> SimResult:
-            key = jax.random.PRNGKey(cfg.seed if seed is None else seed)
-            h_rounds = jnp.swapaxes(h_all, 0, 1)
-            pw = policy_pre(h_rounds) if policy_pre is not None else None
-            with get_telemetry().span("engine.execute"):
-                out = simulate(params, store, data_key, h_rounds, key,
-                               test_x, test_y, pw_all=pw)
-            return _to_result(out[0], out[1], out[2], cfg,
-                              mstate=out[3] if tapped else None)
+        data = (store, data_stream_key(cfg.seed))
     else:
-        xb_all, yb_all = stack_round_batches(client_data, cfg)
+        data = stack_round_batches(client_data, cfg)
 
-        def runner(params, h_all, seed: int | None = None) -> SimResult:
-            key = jax.random.PRNGKey(cfg.seed if seed is None else seed)
-            h_rounds = jnp.swapaxes(h_all, 0, 1)
-            pw = policy_pre(h_rounds) if policy_pre is not None else None
-            with get_telemetry().span("engine.execute"):
-                out = simulate(params, xb_all, yb_all, h_rounds, key,
-                               test_x, test_y, pw_all=pw)
-            return _to_result(out[0], out[1], out[2], cfg,
-                              mstate=out[3] if tapped else None)
+    def call_args(params, h_all, seed):
+        key = jax.random.PRNGKey(cfg.seed if seed is None else seed)
+        h_rounds = jnp.swapaxes(h_all, 0, 1)
+        pw = policy_pre(h_rounds) if policy_pre is not None else None
+        return (params, *data, h_rounds, key, test_x, test_y), {"pw_all": pw}
 
+    def runner(params, h_all, seed: int | None = None) -> SimResult:
+        args, kwargs = call_args(params, h_all, seed)
+        with get_telemetry().span("engine.execute"):
+            out = simulate(*args, **kwargs)
+        return _to_result(out[0], out[1], out[2], cfg,
+                          mstate=out[3] if tapped else None)
+
+    def lower(params, h_all, seed: int | None = None):
+        """The runner's jitted program, lowered for exactly this call."""
+        args, kwargs = call_args(params, h_all, seed)
+        return simulate.lower(*args, **kwargs)
+
+    runner.lower = lower
+    runner.mesh = sim.mesh
     return runner
 
 

@@ -46,14 +46,16 @@ def pseudo_gradients(state: FLState) -> Any:
 
 
 def masked_aggregate(global_params: Any, deltas: Any, mask: jax.Array,
-                     num_clients: int, use_pallas: bool | None = None) -> Any:
+                     num_clients: int,
+                     use_pallas: bool | str | None = None) -> Any:
     """Eq. (3): x ← x + (1/K) Σ_{k∈C_t} δ_k.
 
     ``use_pallas=None`` auto-selects by backend: on TPU every leaf routes
     through the fused ``kernels.fl_aggregate`` kernel (the op sits on the hot
     path of the scan engine, one HBM pass per tile); elsewhere the jnp path is
-    both the oracle and the fastest option.  ``True``/``False`` force a path
-    (``True`` off-TPU runs the kernel in interpret mode — for parity tests).
+    both the oracle and the fastest option.  ``True``/``False`` force a path;
+    ``"interpret"`` runs the kernel body in the Pallas interpreter (the CPU
+    parity tests) — see :mod:`repro.kernels.ops`.
     """
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
@@ -63,7 +65,8 @@ def masked_aggregate(global_params: Any, deltas: Any, mask: jax.Array,
         def agg_k(g, d):
             out = ops.fl_aggregate(g.reshape(-1),
                                    d.reshape(d.shape[0], -1),
-                                   mask.astype(jnp.float32), use_pallas=True)
+                                   mask.astype(jnp.float32),
+                                   use_pallas=use_pallas)
             return out.reshape(g.shape).astype(g.dtype)
 
         return jax.tree_util.tree_map(agg_k, global_params, deltas)
@@ -76,7 +79,7 @@ def masked_aggregate(global_params: Any, deltas: Any, mask: jax.Array,
 
 
 def subset_aggregate(global_params: Any, deltas_p: Any, valid: jax.Array,
-                     num_clients, use_pallas: bool | None = None) -> Any:
+                     num_clients, use_pallas: bool | str | None = None) -> Any:
     """Participant-subset eq. (3): x ← x + (1/K) Σ_p valid_p · δ_p.
 
     ``deltas_p`` carries a leading *participant bucket* axis P (the gathered
@@ -97,7 +100,7 @@ def subset_aggregate(global_params: Any, deltas_p: Any, valid: jax.Array,
         def agg_k(g, d):
             out = ops.fl_aggregate_subset(
                 g.reshape(-1), d.reshape(d.shape[0], -1),
-                valid.astype(jnp.float32), kf, use_pallas=True)
+                valid.astype(jnp.float32), kf, use_pallas=use_pallas)
             return out.reshape(g.shape).astype(g.dtype)
 
         return jax.tree_util.tree_map(agg_k, global_params, deltas_p)
@@ -178,7 +181,7 @@ def guard_weights(deltas: Any, staleness: jax.Array, guards) -> tuple:
 
 def guarded_aggregate(global_params: Any, deltas: Any, mask: jax.Array,
                       num_clients, staleness: jax.Array, guards,
-                      use_pallas: bool | None = None) -> Any:
+                      use_pallas: bool | str | None = None) -> Any:
     """Eq. (3) with server-side defenses: x ← x + (1/K) Σ_k m_k·g_k·δ_k.
 
     ``guards=None`` (or an all-off config) routes straight to
@@ -201,7 +204,7 @@ def guarded_aggregate(global_params: Any, deltas: Any, mask: jax.Array,
         def agg_k(g, d):
             out = ops.fl_aggregate_guarded(g.reshape(-1),
                                            d.reshape(d.shape[0], -1),
-                                           m * inv)
+                                           m * inv, use_pallas=use_pallas)
             return out.reshape(g.shape).astype(g.dtype)
 
         # the kernel zeroes non-finite elements itself — pass raw deltas
@@ -213,7 +216,7 @@ def guarded_aggregate(global_params: Any, deltas: Any, mask: jax.Array,
 def guarded_subset_aggregate(global_params: Any, deltas_p: Any,
                              valid: jax.Array, num_clients,
                              staleness_p: jax.Array, guards,
-                             use_pallas: bool | None = None) -> Any:
+                             use_pallas: bool | str | None = None) -> Any:
     """Participant-subset form of :func:`guarded_aggregate` (sparse path):
     rows are the padded transmitting bucket, ``num_clients`` may be traced."""
     if guards is None or not guards.active:
@@ -230,7 +233,7 @@ def guarded_subset_aggregate(global_params: Any, deltas_p: Any,
         def agg_k(g, d):
             out = ops.fl_aggregate_guarded(g.reshape(-1),
                                            d.reshape(d.shape[0], -1),
-                                           v * inv)
+                                           v * inv, use_pallas=use_pallas)
             return out.reshape(g.shape).astype(g.dtype)
 
         return jax.tree_util.tree_map(agg_k, global_params, deltas_p)
@@ -388,7 +391,7 @@ def scheme_weights(mask: jax.Array, staleness: jax.Array, probs: jax.Array,
 
 
 def weighted_aggregate(global_params: Any, deltas: Any, weights: jax.Array,
-                       use_pallas: bool | None = None) -> Any:
+                       use_pallas: bool | str | None = None) -> Any:
     """Generic weighted update: x ← x + Σ_r a_r·δ_r.
 
     The row axis may be the population (dense engine) or the participant
@@ -406,7 +409,8 @@ def weighted_aggregate(global_params: Any, deltas: Any, weights: jax.Array,
         def agg_k(g, d):
             out = ops.fl_aggregate_guarded(g.reshape(-1),
                                            d.reshape(d.shape[0], -1),
-                                           weights.astype(jnp.float32))
+                                           weights.astype(jnp.float32),
+                                           use_pallas=use_pallas)
             return out.reshape(g.shape).astype(g.dtype)
 
         return jax.tree_util.tree_map(agg_k, global_params, deltas)
@@ -420,7 +424,8 @@ def weighted_aggregate(global_params: Any, deltas: Any, weights: jax.Array,
 
 def scheme_aggregate(global_params: Any, deltas: Any, mask: jax.Array,
                      num_clients, staleness: jax.Array, probs: jax.Array,
-                     agg, guards=None, use_pallas: bool | None = None) -> Any:
+                     agg, guards=None,
+                     use_pallas: bool | str | None = None) -> Any:
     """Population-row aggregation under a pluggable scheme (+ optional
     guards).
 
@@ -443,7 +448,7 @@ def scheme_subset_aggregate(global_params: Any, deltas_p: Any,
                             valid: jax.Array, num_clients,
                             staleness_p: jax.Array, probs_p: jax.Array,
                             agg, guards=None,
-                            use_pallas: bool | None = None) -> Any:
+                            use_pallas: bool | str | None = None) -> Any:
     """Participant-subset form of :func:`scheme_aggregate` (sparse phase B):
     rows are the padded transmitting bucket and ``num_clients`` may be a
     traced scalar, so one compiled bucket program serves every population

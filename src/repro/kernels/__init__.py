@@ -1,8 +1,10 @@
 """Pallas TPU kernels for the perf-critical hot-spots.
 
 Each kernel ships as <name>.py (pl.pallas_call + explicit BlockSpec VMEM
-tiling) with its jnp oracle in ref.py and the jit'd dispatch wrapper in
-ops.py.  Validated in interpret mode on CPU; TPU is the target.
+tiling) with its jnp oracle in ref.py; ops.py dispatches the aggregation
+kernel, the one on the FL path.  The CPU tests run them in interpret mode;
+``tests/test_chip_compile.py`` compiles ``fl_aggregate`` for a described
+TPU v5e, and ``chip_smoke.py`` runs it on the chip against ref.py.
 """
 from . import ops, ref
 from .fl_aggregate import fl_aggregate

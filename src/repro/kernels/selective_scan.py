@@ -20,10 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 DEFAULT_BD = 256
 DEFAULT_SC = 128
 
@@ -40,22 +36,18 @@ def _kernel(xc_ref, dt_ref, bm_ref, cm_ref, a_ref, d_ref, y_ref, h_ref, *,
     Dv = d_ref[...].astype(jnp.float32)[0]             # [BD]
 
     def step(t, h):
-        # leading axis sliced with ds(0, 1), not a bare int: the interpret
-        # path's load discharge rule rejects scalar indexer components on
-        # this jax version
-        lead = pl.ds(0, 1)
-        dt_t = pl.load(dt_ref, (lead, pl.ds(t, 1), slice(None)))[0, 0]  # [BD]
-        x_t = pl.load(xc_ref, (lead, pl.ds(t, 1), slice(None)))[0, 0]
-        b_t = pl.load(bm_ref, (lead, pl.ds(t, 1), slice(None)))[0, 0]   # [N]
-        c_t = pl.load(cm_ref, (lead, pl.ds(t, 1), slice(None)))[0, 0]
+        row = (pl.ds(0, 1), pl.ds(t, 1), slice(None))
+        dt_t = dt_ref[row][0, 0]                       # [BD]
+        x_t = xc_ref[row][0, 0]
+        b_t = bm_ref[row][0, 0]                        # [N]
+        c_t = cm_ref[row][0, 0]
         dt_f = dt_t.astype(jnp.float32)
         dA = jnp.exp(dt_f[:, None] * A)                # [BD, N]
         h = dA * h + (dt_f * x_t.astype(jnp.float32))[:, None] \
             * b_t.astype(jnp.float32)[None, :]
         y = jnp.sum(h * c_t.astype(jnp.float32)[None, :], axis=1) \
             + Dv * x_t.astype(jnp.float32)
-        pl.store(y_ref, (pl.ds(0, 1), pl.ds(t, 1), slice(None)),
-                 y.astype(y_ref.dtype)[None, None, :])
+        y_ref[row] = y.astype(y_ref.dtype)[None, None, :]
         return h
 
     h = jax.lax.fori_loop(0, sc, step, h_ref[...])
@@ -66,7 +58,7 @@ def _kernel(xc_ref, dt_ref, bm_ref, cm_ref, a_ref, d_ref, y_ref, h_ref, *,
 def selective_scan(xc: jax.Array, dt: jax.Array, Bm: jax.Array,
                    Cm: jax.Array, A: jax.Array, D: jax.Array, *,
                    bd: int = DEFAULT_BD, sc: int = DEFAULT_SC,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """xc, dt: [B,S,d]; Bm, Cm: [B,S,N]; A: [d,N]; D: [d] → y [B,S,d] fp32.
 
     d % bd == 0 and S % sc == 0 (pad upstream if needed).
@@ -92,7 +84,7 @@ def selective_scan(xc: jax.Array, dt: jax.Array, Bm: jax.Array,
         out_specs=pl.BlockSpec((1, sc, bd), lambda b, c, s: (b, s, c)),
         out_shape=jax.ShapeDtypeStruct((B, S, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xc, dt, Bm, Cm, A, D.reshape(1, d))

@@ -82,7 +82,7 @@ def collective_bytes(hlo_text: str) -> dict:
 
 def _compile_metrics(spec, mesh) -> dict:
     """lower+compile a ProgramSpec; return {flops, bytes, collectives}."""
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(spec.fn, in_shardings=spec.in_shardings,
                            out_shardings=spec.out_shardings
                            ).lower(*spec.args).compile()
@@ -138,7 +138,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
                  "mode": spec.meta.get("mode", "-")}
     try:
         donate = (0,) if spec.meta["kind"] == "train" else ()
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(spec.fn, in_shardings=spec.in_shardings,
                              out_shardings=spec.out_shardings,
                              donate_argnums=donate)

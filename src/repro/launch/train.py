@@ -36,6 +36,7 @@ from ..data import make_mnist_like, make_token_stream, shard_noniid
 from ..fl import SimConfig, run_simulation
 from ..fl.distributed import fl_train_step, init_dist_state
 from ..models.small import init_mlp, mlp_accuracy, mlp_loss
+from .cache import enable_compile_cache
 
 
 def paper_mode(args) -> None:
@@ -130,6 +131,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.arch:
         arch_mode(args)
     else:

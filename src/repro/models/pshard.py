@@ -12,19 +12,9 @@ from jax.sharding import PartitionSpec as P
 
 
 def _mesh_axis_size(name: str) -> int | None:
-    # get_abstract_mesh landed after jax 0.4.x; fall back to the thread-local
-    # physical mesh on older versions
-    _get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    mesh = _get_abstract() if _get_abstract is not None else None
-    if mesh is None or mesh.empty or name not in mesh.shape:
-        try:
-            from jax._src import mesh as mesh_lib
-            m = mesh_lib.thread_resources.env.physical_mesh
-            if m.empty or name not in m.shape:
-                return None
-            return m.shape[name]
-        except Exception:
-            return None
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or name not in mesh.shape:
+        return None
     return mesh.shape[name]
 
 

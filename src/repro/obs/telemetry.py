@@ -244,20 +244,16 @@ def maybe_profile(out_dir: str | None = None):
 
 def timed_compile(fn, *args, label: str = "jit"):
     """AOT-compile ``fn(*args)`` with spans around each stage —
-    ``<label>.trace`` / ``<label>.lower`` / ``<label>.compile`` (older jax
-    folds trace into lower) — and return the compiled executable.  Wrap its
-    calls in ``span(f"{label}.execute")`` to complete the pipeline timing."""
+    ``<label>.trace`` / ``<label>.lower`` / ``<label>.compile`` — and return
+    the compiled executable.  Wrap its calls in ``span(f"{label}.execute")``
+    to complete the pipeline timing."""
     import jax
 
     tel = get_telemetry()
-    jf = fn if hasattr(fn, "lower") else jax.jit(fn)
-    if hasattr(jf, "trace"):
-        with tel.span(f"{label}.trace"):
-            traced = jf.trace(*args)
-        with tel.span(f"{label}.lower"):
-            lowered = traced.lower()
-    else:
-        with tel.span(f"{label}.lower"):
-            lowered = jf.lower(*args)
+    jf = fn if hasattr(fn, "trace") else jax.jit(fn)
+    with tel.span(f"{label}.trace"):
+        traced = jf.trace(*args)
+    with tel.span(f"{label}.lower"):
+        lowered = traced.lower()
     with tel.span(f"{label}.compile"):
         return lowered.compile()

@@ -93,9 +93,9 @@ def build_apply_fn(guards, aggregator, num_clients: int):
 class MicroBatcher(threading.Thread):
     """Background flush loop.  Holds the server's condition variable only to
     *decide* when to flush; the flush itself (device work) runs unlocked
-    through :meth:`AggregationServer.flush`.  A device-side exception is
-    recorded on :attr:`error` and stops the loop (the server's ``close``
-    drain will re-raise it to the caller)."""
+    through :meth:`AggregationServer.flush`.  A failed flush is recorded on
+    :attr:`error` and stops the loop; the server then fails every pending
+    ticket, refuses new submissions and raises it from ``close``."""
 
     def __init__(self, server):
         super().__init__(daemon=True, name="repro-serve-batcher")
@@ -125,8 +125,9 @@ class MicroBatcher(threading.Thread):
                         continue
             try:
                 srv.flush()
-            except BaseException as e:     # pragma: no cover - defensive
+            except Exception as e:
                 self.error = e
+                srv._fail_pending(e)
                 return
 
     def stop(self, timeout: float = 10.0) -> None:

@@ -106,10 +106,11 @@ class ServeConfig:
 class Ticket:
     """Submission receipt.  ``admitted`` is decided synchronously under the
     ingest lock; for admitted tickets :meth:`wait` blocks until the update
-    aggregates and returns the first server version containing it."""
+    aggregates and returns the first server version containing it, or
+    raises when the micro-batch that held it failed."""
 
     __slots__ = ("client_id", "seq", "admitted", "reason", "arrival_s",
-                 "_event", "_version")
+                 "_event", "_version", "_error")
 
     def __init__(self, client_id: int, seq: int, admitted: bool,
                  reason: str | None = None):
@@ -120,20 +121,30 @@ class Ticket:
         self.arrival_s = time.perf_counter()
         self._event = threading.Event() if admitted else None
         self._version: int | None = None
+        self._error: BaseException | None = None
 
     def done(self) -> bool:
         return bool(self._event and self._event.is_set())
 
     def wait(self, timeout: float | None = None) -> int | None:
-        """Admitted version, or ``None`` on timeout / rejected ticket."""
+        """Admitted version, or ``None`` on timeout / rejected ticket.
+        Raises :class:`RuntimeError` if the update's flush failed."""
         if self._event is None:
             return None
         if not self._event.wait(timeout):
             return None
+        if self._error is not None:
+            raise RuntimeError(
+                f"aggregation of client {self.client_id} (seq {self.seq}) "
+                "failed") from self._error
         return self._version
 
     def _resolve(self, version: int) -> None:
         self._version = version
+        self._event.set()
+
+    def _fail(self, error: BaseException) -> None:
+        self._error = error
         self._event.set()
 
 
@@ -172,6 +183,7 @@ class AggregationServer:
         self._cv = threading.Condition(self._lock)
         self._flush_lock = threading.Lock()
         self._closed = False
+        self._failed: BaseException | None = None
         K = cfg.num_clients
 
         self._global = jax.tree_util.tree_map(jnp.asarray, params)
@@ -249,6 +261,9 @@ class AggregationServer:
         decision returned on the :class:`Ticket`."""
         self._tel.inc("serve.submitted")
         with self._cv:
+            if self._failed is not None:
+                raise RuntimeError("the aggregation server's batcher "
+                                   "failed") from self._failed
             k = int(client_id)
             in_range = 0 <= k < self.cfg.num_clients
             if seq is None:
@@ -312,11 +327,18 @@ class AggregationServer:
             probs = self._probs[ids]
             energy = np.fromiter((p.energy_j for p in batch), np.float32, n)
             deltas = [p.delta for p in batch]
-            with self._tel.span("serve.flush"):
-                g_new = self._apply(g, deltas, bucket,
-                                    jnp.asarray(stale, jnp.int32),
-                                    jnp.asarray(probs, jnp.float32))
-                jax.block_until_ready(g_new)
+            try:
+                with self._tel.span("serve.flush"):
+                    g_new = self._apply(g, deltas, bucket,
+                                        jnp.asarray(stale, jnp.int32),
+                                        jnp.asarray(probs, jnp.float32))
+                    jax.block_until_ready(g_new)
+            except BaseException as e:
+                # the batch already left the pending set: its tickets
+                # must not wait forever on a version that never comes
+                for p in batch:
+                    p.ticket._fail(e)
+                raise
             now = time.perf_counter()
             rec = BatchRecord(
                 t=t, bucket=bucket, ids=tuple(int(i) for i in ids),
@@ -450,7 +472,8 @@ class AggregationServer:
 
     def close(self, drain: bool = True) -> None:
         """Stop admitting, stop the batcher, optionally flush the queue dry
-        (every admitted ticket resolves — the no-drop invariant)."""
+        (every admitted ticket resolves — the no-drop invariant).  Raises
+        :class:`RuntimeError` if the batcher's flush failed."""
         with self._cv:
             self._closed = True
             self._cv.notify_all()
@@ -462,9 +485,23 @@ class AggregationServer:
             self._policy_dirty.set()
             self._policy_thread.join(timeout=30)
             self._policy_thread = None
+        if self._failed is not None:
+            raise RuntimeError("the aggregation server's batcher "
+                               "failed") from self._failed
         if drain:
             while self.flush():
                 pass
+
+    def _fail_pending(self, error: BaseException) -> None:
+        """Batcher death: refuse new submissions and fail every ticket
+        still pending (nothing would ever flush them)."""
+        with self._cv:
+            self._failed = error
+            pending = list(self._pending.values())
+            self._pending.clear()
+            self._cv.notify_all()
+        for p in pending:
+            p.ticket._fail(error)
 
     def __enter__(self) -> "AggregationServer":
         return self

@@ -139,7 +139,8 @@ def test_masked_aggregate_pallas_path_matches_oracle():
     d = pseudo_gradients(st)
     mask = jnp.array([1.0, 0.0, 1.0, 1.0])
     ref = masked_aggregate(st.global_params, d, mask, 4)
-    fused = masked_aggregate(st.global_params, d, mask, 4, use_pallas=True)
+    fused = masked_aggregate(st.global_params, d, mask, 4,
+                             use_pallas="interpret")
     for a, b in zip(jax.tree_util.tree_leaves(ref),
                     jax.tree_util.tree_leaves(fused)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)

@@ -19,7 +19,7 @@ TOL = {jnp.float32: dict(atol=2e-5, rtol=2e-5),
 # fl_aggregate
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("K", [1, 4, 16, 32])
+@pytest.mark.parametrize("K", [1, 4, 16, 32, 130])   # 130: 3 row tiles
 @pytest.mark.parametrize("M", [128, 8192, 8193, 77])   # incl. non-tile sizes
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fl_aggregate_sweep(K, M, dtype):
@@ -62,6 +62,20 @@ def test_fl_aggregate_guard_zeroes_nonfinite(M):
     out = fl_aggregate(g, d, w, interpret=True, denom=1, guard=True)
     want = ref.fl_aggregate_guarded_ref(g, d, w)
     assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               **TOL[jnp.float32])
+
+
+def test_fl_aggregate_guard_across_row_tiles():
+    """Rows past one row tile: poison in a later tile is still quarantined,
+    and the padded rows (mask 0) add nothing."""
+    R, M = 150, 300
+    g = jax.random.normal(jax.random.PRNGKey(0), (M,))
+    d = jax.random.normal(jax.random.PRNGKey(1), (R, M))
+    d = d.at[140].set(jnp.nan).at[70, 5].set(-jnp.inf)
+    w = jnp.full((R,), 1.0 / R).at[140].set(0.0).at[70].set(0.0)
+    out = fl_aggregate(g, d, w, interpret=True, denom=1, guard=True)
+    want = ref.fl_aggregate_guarded_ref(g, d, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                **TOL[jnp.float32])
 

@@ -141,8 +141,13 @@ def test_manual_session_replays_bit_exactly():
     assert server.version == len(server.log.records) > 0
     rep = verify_replay(server, store, params, loss_fn, acc_fn)
     assert rep["ok"] and rep["n_uploads"] == 40
-    # on CPU the live width-1 vmap lane matches the bucketed replay bitwise
-    assert rep["model_max_abs_err"] == 0.0
+    # verify_replay's contract: ledgers exact (asserted inside), the model
+    # within its default rtol/atol — the live width-1 lane and the
+    # bucketed replay are different programs, so equality is not promised
+    served = jax.tree_util.tree_leaves(server.global_params())
+    bound = max(1e-5 + 1e-4 * float(np.max(np.abs(np.asarray(s))))
+                for s in served)
+    assert rep["model_max_abs_err"] <= bound
 
 
 def test_guarded_scheme_session_replays():
@@ -268,6 +273,44 @@ def test_batcher_close_is_idempotent_and_context_managed():
         assert tk.wait(timeout=10) is not None
     server.close()                     # second close is a no-op
     assert server.version >= 1
+
+
+def test_failed_flush_surfaces_to_waiters_submitters_and_close():
+    """A flush that raises inside the batcher thread fails the tickets it
+    held and every pending one, refuses later submissions and makes
+    ``close`` raise — nothing waits forever on a dead batcher."""
+    params, store, loss_fn, acc_fn = _world(K=4)
+    cfg = ServeConfig(num_clients=4, min_bucket=1, flush_interval_s=0.001)
+    server = AggregationServer(params, cfg, start=True)
+
+    def boom(*args, **kwargs):
+        raise ValueError("device fault")
+
+    server._apply = boom
+    d = jax.tree_util.tree_map(jnp.zeros_like, params)
+    tk = server.submit(0, d, 0)
+    assert tk.admitted
+    with pytest.raises(RuntimeError, match="failed") as err:
+        tk.wait(timeout=10)
+    assert isinstance(err.value.__cause__, ValueError)
+    with pytest.raises(RuntimeError, match="batcher failed"):
+        server.submit(1, d, 0)
+    with pytest.raises(RuntimeError, match="batcher failed"):
+        server.close()
+    assert server._batcher is None
+
+
+def test_failed_manual_flush_fails_its_tickets():
+    params, store, loss_fn, acc_fn = _world(K=4)
+    server, _ = _server(params, 4)
+    server._apply = lambda *a, **k: (_ for _ in ()).throw(
+        ValueError("device fault"))
+    d = jax.tree_util.tree_map(jnp.zeros_like, params)
+    tk = server.submit(0, d, 0)
+    with pytest.raises(ValueError, match="device fault"):
+        server.flush()
+    with pytest.raises(RuntimeError, match="failed"):
+        tk.wait(timeout=1)
 
 
 # --- end-to-end: the load generator ------------------------------------------
