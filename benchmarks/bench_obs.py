@@ -7,10 +7,10 @@ The acceptance bound for the default tap set is ≤ 1.10× the untapped
 path; the measured ratio lands in ``BENCH_obs.json`` so
 ``repro.obs.report --diff`` can gate regressions against it.
 
-Also exercises the host-side telemetry layer end to end: the
-``timed_compile`` trace/lower/compile stage spans, run manifests (set
-``REPRO_OBS_DIR`` to persist ``runs.jsonl``), and the compile-cache
-hit/miss counters around the sparse train cache.
+Also exercises the host-side telemetry layer end to end: the runners'
+call spans, run manifests (set ``REPRO_OBS_DIR`` to persist
+``runs.jsonl``), and the compile-cache hit/miss counters around the
+sparse train cache.
 
 Writes ``BENCH_obs.json`` (CI uploads it as an artifact).
 
@@ -22,7 +22,6 @@ import argparse
 import time
 
 import jax
-import jax.numpy as jnp
 
 from repro.core import CellConfig
 from repro.core.selection import RandomScheme, participant_bucket
@@ -31,7 +30,7 @@ from repro.fl.sparse import make_sparse_runner
 from repro.launch.cache import enable_compile_cache
 from repro.models.small import init_mlp, mlp_accuracy, mlp_loss
 from repro.obs import MetricsSpec, metrics_summary
-from repro.obs.telemetry import get_telemetry, timed_compile
+from repro.obs.telemetry import get_telemetry
 
 from .bench_sparse import (DIM, build_store, gains, store_clients,
                            test_set)
@@ -112,14 +111,8 @@ def bench(quick: bool) -> dict:
     print(f"sparse K={K_sparse}: tapped/untapped = "
           f"{out['sparse']['overhead_ratio']:.3f} (bound {BOUND})")
 
-    # timed_compile stage spans on a representative jitted function
-    f = jax.jit(lambda x: jnp.tanh(x @ x.T).sum())
-    timed_compile(f, jnp.ones((64, 64)), label="obs.demo")
-
     tel = get_telemetry()
     snap = tel.snapshot()
-    out["timed_compile_demo"] = {
-        k: v for k, v in snap["spans"].items() if k.startswith("obs.demo")}
     out["telemetry"] = {
         "counters": snap["counters"],
         "spans": snap["spans"],

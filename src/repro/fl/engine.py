@@ -681,24 +681,32 @@ def build_chunk_sim(loss_fn: Callable, acc_fn: Callable, opt: Optimizer,
     return chunk
 
 
+def _to_host(x) -> np.ndarray:
+    """One device→host copy, counted in ``engine.d2h_transfers``."""
+    get_telemetry().inc("engine.d2h_transfers")
+    return np.asarray(x)
+
+
 def _to_result(state, energy, traces, cfg: SimConfig,
-               mstate=None) -> SimResult:
-    """Single end-of-run host readback → legacy ``SimResult``."""
-    did = np.asarray(traces.did_eval)
+               mstate=None, did=None) -> SimResult:
+    """Single end-of-run host readback → legacy ``SimResult``.  ``did`` is
+    ``traces.did_eval`` already on the host, where the caller copied it."""
+    if did is None:
+        did = _to_host(traces.did_eval)
     idx = np.where(did)[0]
-    e_round = np.asarray(traces.e_round)               # [T, K]
+    e_round = _to_host(traces.e_round)                 # [T, K]
     faulty = cfg.faults is not None
     return SimResult(
-        test_acc=np.asarray(traces.acc)[idx],
-        test_loss=np.asarray(traces.loss)[idx],
+        test_acc=_to_host(traces.acc)[idx],
+        test_loss=_to_host(traces.loss)[idx],
         eval_rounds=idx,
-        energy_per_client=np.asarray(energy),
+        energy_per_client=_to_host(energy),
         energy_timeline=np.cumsum(e_round.sum(axis=1)),
-        participation=np.asarray(traces.mask),
+        participation=_to_host(traces.mask),
         state=state,
-        delivered=np.asarray(traces.delivered) if faulty else None,
-        corrupted=np.asarray(traces.corrupt) if faulty else None,
-        metrics=(jax.tree_util.tree_map(np.asarray, mstate)
+        delivered=_to_host(traces.delivered) if faulty else None,
+        corrupted=_to_host(traces.corrupt) if faulty else None,
+        metrics=(jax.tree_util.tree_map(_to_host, mstate)
                  if mstate is not None else None),
     )
 
@@ -727,7 +735,7 @@ def _make_stream_runner(loss_fn: Callable, acc_fn: Callable,
     C = max(1, int(cfg.stream_chunk))
     bounds = [(t0, min(t0 + C, T)) for t0 in range(0, T, C)]
 
-    def runner(params, h_all, seed: int | None = None) -> SimResult:
+    def run(params, h_all, seed):
         key = jax.random.PRNGKey(cfg.seed if seed is None else seed)
         h_rounds = jnp.swapaxes(h_all, 0, 1)
         pw_full = (pol(ts_full, h_rounds) if hoist
@@ -742,11 +750,17 @@ def _make_stream_runner(loss_fn: Callable, acc_fn: Callable,
             if i + 1 < len(bounds):   # prefetch overlaps the async chunk
                 buf = sampler.chunk(*bounds[i + 1])
             traces.append(tr)
-        state, energy = carry[0], carry[1]
         traces = jax.tree_util.tree_map(
             lambda *xs: jnp.concatenate(xs, axis=0), *traces)
-        return _to_result(state, energy, traces, cfg,
-                          mstate=carry[-1] if tapped else None)
+        return carry, traces
+
+    def runner(params, h_all, seed: int | None = None) -> SimResult:
+        tel = get_telemetry()
+        with tel.span("engine.call", call_id=tel.inc("engine.calls")):
+            carry, traces = run(params, h_all, seed)
+            with tel.span("engine.readback"):
+                return _to_result(carry[0], carry[1], traces, cfg,
+                                  mstate=carry[-1] if tapped else None)
 
     return runner
 
@@ -820,11 +834,17 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
         return (params, *data, h_rounds, key, test_x, test_y), {"pw_all": pw}
 
     def runner(params, h_all, seed: int | None = None) -> SimResult:
-        args, kwargs = call_args(params, h_all, seed)
-        with get_telemetry().span("engine.execute"):
-            out = simulate(*args, **kwargs)
-        return _to_result(out[0], out[1], out[2], cfg,
-                          mstate=out[3] if tapped else None)
+        tel = get_telemetry()
+        with tel.span("engine.call", call_id=tel.inc("engine.calls")):
+            with tel.span("engine.prepare"):
+                args, kwargs = call_args(params, h_all, seed)
+            with tel.span("engine.dispatch"):
+                out = simulate(*args, **kwargs)
+            with tel.span("engine.wait"):   # this copy waits for the device
+                did = _to_host(out[2].did_eval)
+            with tel.span("engine.readback"):
+                return _to_result(out[0], out[1], out[2], cfg,
+                                  mstate=out[3] if tapped else None, did=did)
 
     def lower(params, h_all, seed: int | None = None):
         """The runner's jitted program, lowered for exactly this call."""
@@ -931,8 +951,7 @@ def run_seed_matrix(init_params, loss_fn, acc_fn, client_data, test_ds,
                                     test_x, test_y)))
     emit_run_manifest("run_seed_matrix", cfg,
                       extra={"lanes": len(seeds), "num_clients": K})
-    with get_telemetry().span("seed_matrix.execute"):
-        out = fan(keys, h_rounds)
+    out = fan(keys, h_rounds)
     tapped = metrics_active(cfg.metrics, cfg.guards)
     return _matrix_result(out[1], out[2],
                           mstate=out[3] if tapped else None)
@@ -978,8 +997,7 @@ def run_scenario_matrix(init_params, loss_fn, acc_fn, client_data, test_ds,
     emit_run_manifest("run_scenario_matrix", cfg,
                       extra={"rhos": len(rhos), "lanes": len(seeds),
                              "num_clients": K})
-    with get_telemetry().span("scenario_matrix.execute"):
-        out = fan(jnp.asarray(rhos, jnp.float32), keys, h_rounds)
+    out = fan(jnp.asarray(rhos, jnp.float32), keys, h_rounds)
     tapped = metrics_active(cfg.metrics, cfg.guards)
     return _matrix_result(out[1], out[2],
                           mstate=out[3] if tapped else None)

@@ -440,7 +440,7 @@ def run_fault_matrix(init_params, loss_fn, acc_fn, client_data, test_ds,
     fp_stack = jax.vmap(lambda r: scale_params(base_fp, r))(rates_arr)
 
     from ..obs.taps import metrics_active
-    from ..obs.telemetry import emit_run_manifest, get_telemetry
+    from ..obs.telemetry import emit_run_manifest
     emit_run_manifest("run_fault_matrix", cfg,
                       extra={"rates": len(rates), "num_clients": int(K)})
 
@@ -457,8 +457,7 @@ def run_fault_matrix(init_params, loss_fn, acc_fn, client_data, test_ds,
         fan = jax.jit(jax.vmap(
             lambda fp: sim(init_params, data[0], data[1], h_rounds, key,
                            test_x, test_y, fault_params=fp)))
-        with get_telemetry().span("fault_matrix.execute"):
-            out = fan(fp_stack)
+        out = fan(fp_stack)
         state, energy, traces = out[0], out[1], out[2]
         if tapped:
             out_ms[name] = jax.tree_util.tree_map(np.asarray, out[3])

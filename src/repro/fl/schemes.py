@@ -48,7 +48,7 @@ from ..core.selection import (as_policy_fn, participant_bucket,
 from ..data.device import (DeviceDataStore, data_stream_key,
                            from_client_datasets, gather_participant_rounds)
 from ..obs.taps import merge_metrics, metrics_active
-from ..obs.telemetry import emit_run_manifest, get_telemetry
+from ..obs.telemetry import emit_run_manifest
 from ..optim import Optimizer, sgd
 from .state import AggParams, AggregatorConfig
 
@@ -234,8 +234,7 @@ def run_scheme_matrix(init_params, loss_fn: Callable, acc_fn: Callable,
         scheme_lanes = jax.vmap(seed_lanes, in_axes=(0, 0, None, None, None))
         fan = jax.jit(jax.vmap(scheme_lanes,
                                in_axes=(None, None, None, None, 0)))
-        with get_telemetry().span("scheme_matrix.execute"):
-            out = fan(sel_eye, ap_stack, keys, h_rounds, store_stack)
+        out = fan(sel_eye, ap_stack, keys, h_rounds, store_stack)
         energy, traces = out[1], out[2]
         ms = out[3] if tapped else None
         e_round = np.asarray(traces.e_round)            # [V, L, S, T, K]
@@ -303,9 +302,8 @@ def run_scheme_matrix(init_params, loss_fn: Callable, acc_fn: Callable,
     scheme_lanes = jax.vmap(seed_lanes, in_axes=(0, 0, None, None, None))
     fan = jax.jit(jax.vmap(scheme_lanes,
                            in_axes=(None, None, None, None, 0)))
-    with get_telemetry().span("scheme_matrix.execute"):
-        energy, accs, losses, dids, ptr, ms = fan(sel_eye, ap_stack, keys,
-                                                  h_rounds, store_stack)
+    energy, accs, losses, dids, ptr, ms = fan(sel_eye, ap_stack, keys,
+                                              h_rounds, store_stack)
     n_tx = np.asarray(ptr.n_tx)
     if (n_tx > bucket).any():
         raise RuntimeError(

@@ -211,7 +211,7 @@ def build_participation_program(policy_fn, cfg, cell: CellConfig,
                 "state through the round scan)")
         full_hoist = bool(hoist_rounds)
 
-    def program(h_rounds, base_key):
+    def phase_a_program(h_rounds, base_key):
         ts = jnp.arange(cfg.rounds, dtype=jnp.int32)
         if hoist:
             pw_all = jax.vmap(lambda t, h: policy_fn(t, h, None))(
@@ -327,7 +327,7 @@ def build_participation_program(policy_fn, cfg, cell: CellConfig,
                 tr, cfg.metrics, K, cfg.rounds)
         return final[0], final[2], tr
 
-    return program
+    return phase_a_program
 
 
 # ---------------------------------------------------------------------------
@@ -387,9 +387,10 @@ def build_sparse_train_program(loss_fn: Callable, acc_fn: Callable,
     # reductions to associativity (split accumulation with phase A)
     ttap = metrics_active(cfg.metrics, guards, parts="train")
 
-    def program(params, xb_all, yb_all, valid_all, slot_all, num_clients,
-                test_x, test_y, delivered_all=None, corrupt_all=None,
-                stale_all=None, probs_all=None, agg_params=None):
+    def phase_b_program(params, xb_all, yb_all, valid_all, slot_all,
+                        num_clients, test_x, test_y, delivered_all=None,
+                        corrupt_all=None, stale_all=None, probs_all=None,
+                        agg_params=None):
         global TRAIN_TRACE_COUNT
         TRAIN_TRACE_COUNT += 1
         hist0 = jax.tree_util.tree_map(
@@ -459,7 +460,7 @@ def build_sparse_train_program(loss_fn: Callable, acc_fn: Callable,
             return g_final, traces, final[1]
         return g_final, traces
 
-    return program
+    return phase_b_program
 
 
 def _cached_train_program(key, builder: Callable) -> Callable:
@@ -556,87 +557,96 @@ def make_sparse_runner(loss_fn: Callable, acc_fn: Callable,
                 policy_fn, cfg, cell, K, bucket))
         else:
             tel.inc("sparse.phase_a_cache_hit")
-        with tel.span("sparse.phase_a"):
-            return phase_a[bucket](h_rounds, key)
+        return phase_a[bucket](h_rounds, key)
 
     def runner(params, h_all, seed: int | None = None) -> SimResult:
-        key = jax.random.PRNGKey(cfg.seed if seed is None else seed)
-        h_rounds = jnp.swapaxes(h_all, 0, 1)
-        bucket = cfg.participant_bucket or _auto_bucket(policy_fn, h_rounds,
-                                                        cfg, K)
-        pa = _phase_a(bucket, h_rounds, key)
-        last_tx, energy, ptr = pa[0], pa[1], pa[2]
-        ms_a = pa[3] if ltap else None
-        n_tx = np.asarray(ptr.n_tx)
-        if (n_tx > bucket).any():
-            if cfg.overflow == "error":
-                raise RuntimeError(
-                    f"participant bucket overflow: round "
-                    f"{int(n_tx.argmax())} realized {int(n_tx.max())} "
-                    f"transmitters > bucket {bucket} — pass "
-                    "SimConfig(participant_bucket=...) with more headroom")
-            # spill fallback: regrow toward the dense width (next power of
-            # two ≥ the realized max, capped at K) and rerun phase A —
-            # decision math is bucket-independent, so the rerun is exact
-            grown = max(bucket, 1)
-            while grown < int(n_tx.max()):
-                grown *= 2
-            grown = min(grown, K)
-            _warn_spill_once(bucket, grown, int(n_tx.max()))
-            bucket = grown
+        with tel.span("sparse.call"):
+            return _call(params, h_all, seed)
+
+    def _call(params, h_all, seed):
+        with tel.span("sparse.prepare"):
+            key = jax.random.PRNGKey(cfg.seed if seed is None else seed)
+            h_rounds = jnp.swapaxes(h_all, 0, 1)
+            bucket = cfg.participant_bucket or _auto_bucket(
+                policy_fn, h_rounds, cfg, K)
+        with tel.span("sparse.phase_a"):    # ends at its n_tx sync
             pa = _phase_a(bucket, h_rounds, key)
             last_tx, energy, ptr = pa[0], pa[1], pa[2]
             ms_a = pa[3] if ltap else None
-        xb_all, yb_all = gather(ptr.part_idx)
-        train = _cached_train_program(
-            _train_cache_key(cfg, opt_token, loss_fn, acc_fn, params,
-                             store.x.shape[2:], test_x.shape, bucket),
-            lambda: build_sparse_train_program(loss_fn, acc_fn, opt, cfg))
-        with tel.span("sparse.train"):
+            n_tx = np.asarray(ptr.n_tx)
+            if (n_tx > bucket).any():
+                if cfg.overflow == "error":
+                    raise RuntimeError(
+                        f"participant bucket overflow: round "
+                        f"{int(n_tx.argmax())} realized {int(n_tx.max())} "
+                        f"transmitters > bucket {bucket} — pass "
+                        "SimConfig(participant_bucket=...) with more "
+                        "headroom")
+                # spill fallback: regrow toward the dense width (next power
+                # of two ≥ the realized max, capped at K) and rerun phase
+                # A — decision math is bucket-independent, so the rerun is
+                # exact
+                grown = max(bucket, 1)
+                while grown < int(n_tx.max()):
+                    grown *= 2
+                grown = min(grown, K)
+                _warn_spill_once(bucket, grown, int(n_tx.max()))
+                bucket = grown
+                pa = _phase_a(bucket, h_rounds, key)
+                last_tx, energy, ptr = pa[0], pa[1], pa[2]
+                ms_a = pa[3] if ltap else None
+        # phase B ends at the first copy of its outputs; the host densifies
+        # phase A's participant trace (numpy, O(T·K)) while the device trains
+        with tel.span("sparse.phase_b"):
+            xb_all, yb_all = gather(ptr.part_idx)
+            train = _cached_train_program(
+                _train_cache_key(cfg, opt_token, loss_fn, acc_fn, params,
+                                 store.x.shape[2:], test_x.shape, bucket),
+                lambda: build_sparse_train_program(loss_fn, acc_fn, opt, cfg))
             out = train(
                 params, xb_all, yb_all, ptr.valid, ptr.anchor_slot,
                 jnp.int32(K), test_x, test_y, ptr.delivered, ptr.corrupt,
                 ptr.stale, ptr.prob)
-        g_final, (accs, losses, dids) = out[0], out[1]
-        ms_b = out[2] if ttap else None
+            g_final, (accs, losses, dids) = out[0], out[1]
+            ms_b = out[2] if ttap else None
 
-        # host-side densification of the participant trace (numpy, O(T·K))
-        idx = np.asarray(ptr.part_idx)
-        val = np.asarray(ptr.valid)
-        e_p = np.asarray(ptr.e_p)
-        t_of = np.broadcast_to(np.arange(T)[:, None], idx.shape)
-        parts = np.zeros((T, K), np.float32)
-        e_round = np.zeros((T, K), np.float32)
-        parts[t_of[val], idx[val]] = 1.0
-        e_round[t_of[val], idx[val]] = e_p[val]
-        did = np.asarray(dids)
-        ev = np.where(did)[0]
-        state = FLState(global_params=g_final, client_params=None,
-                        anchor_params=None, round=jnp.int32(T),
-                        last_tx=last_tx)
-        if cfg.faults is not None:
-            dlv = np.asarray(ptr.delivered)
-            cor = np.asarray(ptr.corrupt)
-            delivered = np.zeros((T, K), np.float32)
-            corrupted = np.zeros((T, K), np.float32)
-            delivered[t_of[val], idx[val]] = dlv[val].astype(np.float32)
-            corrupted[t_of[val], idx[val]] = cor[val].astype(np.float32)
-        else:
-            delivered = corrupted = None
-        ms = merge_metrics(ms_a, ms_b)
-        return SimResult(
-            test_acc=np.asarray(accs)[ev],
-            test_loss=np.asarray(losses)[ev],
-            eval_rounds=ev,
-            energy_per_client=np.asarray(energy),
-            energy_timeline=np.cumsum(e_round.sum(axis=1)),
-            participation=parts,
-            state=state,
-            delivered=delivered,
-            corrupted=corrupted,
-            metrics=(jax.tree_util.tree_map(np.asarray, ms)
-                     if ms is not None else None),
-        )
+            idx = np.asarray(ptr.part_idx)
+            val = np.asarray(ptr.valid)
+            e_p = np.asarray(ptr.e_p)
+            t_of = np.broadcast_to(np.arange(T)[:, None], idx.shape)
+            parts = np.zeros((T, K), np.float32)
+            e_round = np.zeros((T, K), np.float32)
+            parts[t_of[val], idx[val]] = 1.0
+            e_round[t_of[val], idx[val]] = e_p[val]
+            did = np.asarray(dids)
+        with tel.span("sparse.readback"):
+            ev = np.where(did)[0]
+            state = FLState(global_params=g_final, client_params=None,
+                            anchor_params=None, round=jnp.int32(T),
+                            last_tx=last_tx)
+            if cfg.faults is not None:
+                dlv = np.asarray(ptr.delivered)
+                cor = np.asarray(ptr.corrupt)
+                delivered = np.zeros((T, K), np.float32)
+                corrupted = np.zeros((T, K), np.float32)
+                delivered[t_of[val], idx[val]] = dlv[val].astype(np.float32)
+                corrupted[t_of[val], idx[val]] = cor[val].astype(np.float32)
+            else:
+                delivered = corrupted = None
+            ms = merge_metrics(ms_a, ms_b)
+            return SimResult(
+                test_acc=np.asarray(accs)[ev],
+                test_loss=np.asarray(losses)[ev],
+                eval_rounds=ev,
+                energy_per_client=np.asarray(energy),
+                energy_timeline=np.cumsum(e_round.sum(axis=1)),
+                participation=parts,
+                state=state,
+                delivered=delivered,
+                corrupted=corrupted,
+                metrics=(jax.tree_util.tree_map(np.asarray, ms)
+                         if ms is not None else None),
+            )
 
     runner.store = store
     return runner

@@ -12,25 +12,33 @@ just dict updates); *writing* anything to disk is opt-in:
   wraps a block in ``jax.profiler.trace`` emitting a TensorBoard trace.
 
 Spans aggregate per name (count / total / max seconds) so a million runner
-calls cost a bounded dict, not an unbounded event log.  The sparse train
-compile cache (:mod:`repro.fl.sparse`) bumps the
+calls cost a bounded dict.  Each span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so it lands on the host plane
+of any profiler trace on the device events' clock, and appends a
+:class:`SpanRecord` (name, call id, parent, start, end) to a ring of the
+last :data:`MAX_RECORDS` spans, from which one window's calls can be read
+back.  The sparse train compile cache (:mod:`repro.fl.sparse`) bumps the
 ``sparse.train_cache_{hit,miss}`` counters here.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import hashlib
 import json
 import os
 import subprocess
+import threading
 import time
-from typing import Any
+from typing import Any, NamedTuple
 
-__all__ = ["Telemetry", "get_telemetry", "configure", "env_fingerprint",
-           "config_fingerprint", "run_manifest", "emit_run_manifest",
-           "validate_manifest", "maybe_profile", "timed_compile",
-           "MANIFEST_SCHEMA", "MANIFEST_SCHEMA_VERSION"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Telemetry", "SpanRecord", "get_telemetry", "configure",
+           "env_fingerprint", "config_fingerprint", "run_manifest",
+           "emit_run_manifest", "validate_manifest", "maybe_profile",
+           "MANIFEST_SCHEMA", "MANIFEST_SCHEMA_VERSION", "MAX_RECORDS"]
 
 MANIFEST_SCHEMA_VERSION = 1
 
@@ -52,32 +60,93 @@ _FINGERPRINT_KEYS = ("git_sha", "jax", "jaxlib", "backend", "device_count",
 #: cap on the in-memory manifest record (append-only; old entries rotate).
 _MAX_MANIFESTS = 256
 
+#: cap on the span record ring: a one-second traced window of the dense
+#: runner holds ~50 calls of 5 spans each
+MAX_RECORDS = 4096
+
+
+class SpanRecord(NamedTuple):
+    """One closed span.  ``call_id`` is the id given to the outermost span
+    of a call (e.g. ``engine.call``) and inherited by every span opened
+    inside it on the same thread; ``parent`` names the innermost span open
+    when this one began (``None`` for both at top level).  Times are
+    ``time.perf_counter()`` seconds."""
+
+    name: str
+    call_id: int | None
+    parent: str | None
+    start_s: float
+    end_s: float
+
+
+class _OpenSpans(threading.local):
+    """Each thread's stack of open spans, ``[(name, call_id)]``."""
+
+    def __init__(self):
+        self.stack: list = []
+
+
+class _Span:
+    """``Telemetry.span``'s context manager: a profiler annotation, the
+    per-name aggregate and one :class:`SpanRecord`."""
+
+    __slots__ = ("tel", "name", "call_id", "parent", "ann", "t0")
+
+    def __init__(self, tel: "Telemetry", name: str, call_id: int | None):
+        self.tel, self.name, self.call_id = tel, name, call_id
+
+    def __enter__(self):
+        stack = self.tel._open.stack
+        self.parent, outer = stack[-1] if stack else (None, None)
+        if self.call_id is None:
+            self.call_id = outer
+        stack.append((self.name, self.call_id))
+        self.ann = TraceAnnotation(self.name)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self.ann.__exit__(*exc)
+        self.tel._open.stack.pop()
+        dt = t1 - self.t0
+        c = self.tel.spans.setdefault(self.name, [0, 0.0, 0.0])
+        c[0] += 1
+        c[1] += dt
+        c[2] = max(c[2], dt)
+        self.tel.ring.append(SpanRecord(self.name, self.call_id,
+                                        self.parent, self.t0, t1))
+        return False
+
 
 class Telemetry:
     """Process-wide aggregation sink: counters, named spans, manifests."""
 
     def __init__(self):
+        self._open = _OpenSpans()
         self.reset()
 
     def reset(self) -> None:
         self.counters: dict = {}
         self.spans: dict = {}          # name -> [count, total_s, max_s]
+        self.ring = collections.deque(maxlen=MAX_RECORDS)
         self.manifests: list = []
 
-    def inc(self, name: str, n: int = 1) -> None:
+    def inc(self, name: str, n: int = 1) -> int:
+        """Add ``n`` to a counter; returns its new value (a call's id)."""
         self.counters[name] = self.counters.get(name, 0) + n
+        return self.counters[name]
 
-    @contextlib.contextmanager
-    def span(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            c = self.spans.setdefault(name, [0, 0.0, 0.0])
-            c[0] += 1
-            c[1] += dt
-            c[2] = max(c[2], dt)
+    def span(self, name: str, call_id: int | None = None) -> _Span:
+        """Time a block under ``name``.  ``call_id`` marks the outermost
+        span of a call; spans opened inside inherit it."""
+        return _Span(self, name, call_id)
+
+    def records(self, name: str | None = None) -> list:
+        """The ring's :class:`SpanRecord`s, oldest first (only ``name``'s
+        when given)."""
+        return [r for r in self.ring if name is None or r.name == name]
 
     def span_stats(self, name: str) -> dict | None:
         c = self.spans.get(name)
@@ -240,20 +309,3 @@ def maybe_profile(out_dir: str | None = None):
     os.makedirs(d, exist_ok=True)
     with jax.profiler.trace(d):
         yield d
-
-
-def timed_compile(fn, *args, label: str = "jit"):
-    """AOT-compile ``fn(*args)`` with spans around each stage —
-    ``<label>.trace`` / ``<label>.lower`` / ``<label>.compile`` — and return
-    the compiled executable.  Wrap its calls in ``span(f"{label}.execute")``
-    to complete the pipeline timing."""
-    import jax
-
-    tel = get_telemetry()
-    jf = fn if hasattr(fn, "trace") else jax.jit(fn)
-    with tel.span(f"{label}.trace"):
-        traced = jf.trace(*args)
-    with tel.span(f"{label}.lower"):
-        lowered = traced.lower()
-    with tel.span(f"{label}.compile"):
-        return lowered.compile()

@@ -19,8 +19,7 @@ from repro.fl.engine import build_scan_sim, init_carry
 from repro.fl.resume import read_segment_manifest, run_resumable
 from repro.fl.schemes import run_scheme_matrix
 from repro.models.small import mlp_accuracy, mlp_loss
-from repro.obs import (MetricsSpec, metrics_summary, timed_compile,
-                       validate_manifest)
+from repro.obs import MetricsSpec, metrics_summary, validate_manifest
 from repro.obs import report as obs_report
 from repro.obs.telemetry import emit_run_manifest, get_telemetry
 from repro.optim import sgd
@@ -211,7 +210,7 @@ def test_fault_matrix_taps_per_guard_setting():
     assert res.metrics["guarded"].guard_events is not None
 
 
-# --- telemetry: manifests, spans, timed_compile -----------------------------
+# --- telemetry: manifests ----------------------------------------------------
 
 
 def test_manifest_emit_validate_jsonl_roundtrip(tmp_path, monkeypatch):
@@ -244,14 +243,6 @@ def test_runners_emit_manifests():
         assert validate_manifest(m) == []
 
 
-def test_timed_compile_records_stage_spans():
-    tel = get_telemetry()
-    compiled = timed_compile(jax.jit(lambda x: (x * 2.0).sum()),
-                             jnp.ones((8, 8)), label="obs_test")
-    assert float(compiled(jnp.ones((8, 8)))) == 128.0
-    assert tel.span_stats("obs_test.compile")["count"] >= 1
-    assert (tel.span_stats("obs_test.lower") or
-            tel.span_stats("obs_test.trace"))
 
 
 # --- reporter: diff gate ----------------------------------------------------
