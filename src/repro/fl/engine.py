@@ -30,6 +30,8 @@ See ``docs/engine.md`` for the full architecture notes.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Any, Callable, NamedTuple, Sequence
 
 import jax
@@ -687,28 +689,82 @@ def _to_host(x) -> np.ndarray:
     return np.asarray(x)
 
 
-def _to_result(state, energy, traces, cfg: SimConfig,
-               mstate=None, did=None) -> SimResult:
-    """Single end-of-run host readback → legacy ``SimResult``.  ``did`` is
-    ``traces.did_eval`` already on the host, where the caller copied it."""
-    if did is None:
-        did = _to_host(traces.did_eval)
-    idx = np.where(did)[0]
-    e_round = _to_host(traces.e_round)                 # [T, K]
+def _host_fields(energy, traces, cfg: SimConfig, mstate=None) -> dict:
+    """The run's outputs a ``SimResult`` is read from (``None`` where the
+    configuration has no such output)."""
     faulty = cfg.faults is not None
+    return {"did_eval": traces.did_eval, "e_round": traces.e_round,
+            "acc": traces.acc, "loss": traces.loss, "energy": energy,
+            "mask": traces.mask,
+            "delivered": traces.delivered if faulty else None,
+            "corrupt": traces.corrupt if faulty else None,
+            "metrics": mstate}
+
+
+def _host_result(state, host: dict) -> SimResult:
+    """``SimResult`` from :func:`_host_fields` already on the host."""
+    idx = np.where(host["did_eval"])[0]
     return SimResult(
-        test_acc=_to_host(traces.acc)[idx],
-        test_loss=_to_host(traces.loss)[idx],
+        test_acc=host["acc"][idx],
+        test_loss=host["loss"][idx],
         eval_rounds=idx,
-        energy_per_client=_to_host(energy),
-        energy_timeline=np.cumsum(e_round.sum(axis=1)),
-        participation=_to_host(traces.mask),
+        energy_per_client=host["energy"],
+        energy_timeline=np.cumsum(host["e_round"].sum(axis=1)),
+        participation=host["mask"],
         state=state,
-        delivered=_to_host(traces.delivered) if faulty else None,
-        corrupted=_to_host(traces.corrupt) if faulty else None,
-        metrics=(jax.tree_util.tree_map(_to_host, mstate)
-                 if mstate is not None else None),
+        delivered=host["delivered"],
+        corrupted=host["corrupt"],
+        metrics=host["metrics"],
     )
+
+
+def _to_result(state, energy, traces, cfg: SimConfig,
+               mstate=None) -> SimResult:
+    """End-of-run host readback → legacy ``SimResult``: one copy per array
+    the host reads."""
+    return _host_result(state, jax.tree_util.tree_map(
+        _to_host, _host_fields(energy, traces, cfg, mstate)))
+
+
+@functools.partial(jax.tree_util.register_dataclass, data_fields=["words"],
+                   meta_fields=["layout"])
+@dataclasses.dataclass(frozen=True)
+class _Record:
+    """A pytree of arrays packed into one flat ``uint32`` buffer.  The
+    layout (tree structure, each leaf's dtype and shape) is static pytree
+    metadata, so it rides in the compiled program's output structure."""
+
+    words: Any
+    layout: tuple
+
+
+def _pack(tree) -> _Record:
+    """Pack ``tree``'s leaves (bool and 32-bit) into one ``uint32`` buffer,
+    bit for bit: bools widened, the rest by ``bitcast_convert_type``."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    words = []
+    for x in leaves:
+        if x.dtype == jnp.bool_:
+            words.append(x.astype(jnp.uint32).reshape(-1))
+        elif x.dtype.itemsize == 4:
+            words.append(jax.lax.bitcast_convert_type(x, jnp.uint32)
+                         .reshape(-1))
+        else:
+            raise TypeError(f"cannot pack a {x.dtype} leaf into 32-bit words")
+    layout = (treedef, tuple((np.dtype(x.dtype), x.shape) for x in leaves))
+    return _Record(jnp.concatenate(words), layout)
+
+
+def _unpack(words: np.ndarray, layout: tuple):
+    """Inverse of :func:`_pack` on the host copy of ``words``."""
+    treedef, specs = layout
+    leaves, at = [], 0
+    for dt, shape in specs:
+        n = math.prod(shape)
+        w = words[at:at + n].reshape(shape)
+        at += n
+        leaves.append(w != 0 if dt == np.bool_ else w.view(dt))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def _make_stream_runner(loss_fn: Callable, acc_fn: Callable,
@@ -785,10 +841,15 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
     store's client axis is placed on the same mesh as the FL state whenever
     client-axis sharding is active.
 
-    The dense scan runner also carries ``runner.lower(params, h_all,
-    seed=None)`` — its program lowered for that call, for inspecting what
-    was compiled — and ``runner.mesh``, the client-axis mesh (``None`` when
-    unsharded).
+    The dense scan runner crosses the host boundary once each way per call:
+    the seed and ``h_all`` go into its program as host values (no eager
+    device work before the launch), and every array the ``SimResult`` reads
+    comes back in one packed ``uint32`` record, copied once and unpacked
+    bit for bit on the host; ``SimResult.state`` stays on the device.  It
+    also carries ``runner.call_args(params, h_all, seed=None)`` and
+    ``runner.lower(params, h_all, seed=None)`` — its program's arguments,
+    and its program lowered, for that call — and ``runner.mesh``, the
+    client-axis mesh (``None`` when unsharded).
     """
     K = len(client_data)
     policy_fn = as_policy_fn(policy)
@@ -813,9 +874,21 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
 
     sim = build_scan_sim(loss_fn, acc_fn, opt, cfg, cell, K, policy_fn,
                          shard_clients=shard_clients, data_mode=path)
-    simulate = jax.jit(sim)
-    policy_pre = jax.jit(sim.hoisted_policy) if sim.split_policy else None
     tapped = metrics_active(cfg.metrics, cfg.guards)
+
+    def program(params, data, h_all, seed, test_x, test_y, pw_all):
+        """One call on the device: the key and the round-major channel are
+        made here from the raw host values, and what the host reads comes
+        back as one packed record beside the device-resident state."""
+        key = jax.random.PRNGKey(seed)
+        out = sim(params, *data, jnp.swapaxes(h_all, 0, 1), key, test_x,
+                  test_y, pw_all=pw_all)
+        return out[0], _pack(_host_fields(out[1], out[2], cfg,
+                                          out[3] if tapped else None))
+
+    simulate = jax.jit(program)
+    policy_pre = (jax.jit(lambda h_all: sim.hoisted_policy(
+        jnp.swapaxes(h_all, 0, 1))) if sim.split_policy else None)
 
     if path == "device":
         store = from_client_datasets(client_data)
@@ -827,30 +900,32 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
     else:
         data = stack_round_batches(client_data, cfg)
 
-    def call_args(params, h_all, seed):
-        key = jax.random.PRNGKey(cfg.seed if seed is None else seed)
-        h_rounds = jnp.swapaxes(h_all, 0, 1)
-        pw = policy_pre(h_rounds) if policy_pre is not None else None
-        return (params, *data, h_rounds, key, test_x, test_y), {"pw_all": pw}
+    def call_args(params, h_all, seed=None):
+        """The program's arguments for this call.  The seed goes in as a
+        ``uint32``: in-program ``PRNGKey`` of it is bit-identical to the
+        eager ``PRNGKey(seed)`` (32-bit mode keys any integer by its low 32
+        bits); ``h_all`` goes in as given."""
+        seed = np.uint32(int(cfg.seed if seed is None else seed) % 2 ** 32)
+        pw = policy_pre(h_all) if policy_pre is not None else None
+        return params, data, h_all, seed, test_x, test_y, pw
 
     def runner(params, h_all, seed: int | None = None) -> SimResult:
         tel = get_telemetry()
         with tel.span("engine.call", call_id=tel.inc("engine.calls")):
             with tel.span("engine.prepare"):
-                args, kwargs = call_args(params, h_all, seed)
+                args = call_args(params, h_all, seed)
             with tel.span("engine.dispatch"):
-                out = simulate(*args, **kwargs)
-            with tel.span("engine.wait"):   # this copy waits for the device
-                did = _to_host(out[2].did_eval)
+                state, record = simulate(*args)
+            with tel.span("engine.wait"):   # the one copy waits for the device
+                words = _to_host(record.words)
             with tel.span("engine.readback"):
-                return _to_result(out[0], out[1], out[2], cfg,
-                                  mstate=out[3] if tapped else None, did=did)
+                return _host_result(state, _unpack(words, record.layout))
 
     def lower(params, h_all, seed: int | None = None):
         """The runner's jitted program, lowered for exactly this call."""
-        args, kwargs = call_args(params, h_all, seed)
-        return simulate.lower(*args, **kwargs)
+        return simulate.lower(*call_args(params, h_all, seed))
 
+    runner.call_args = call_args
     runner.lower = lower
     runner.mesh = sim.mesh
     return runner
