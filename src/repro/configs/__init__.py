@@ -12,15 +12,15 @@ import dataclasses
 from .base import ArchConfig, MoEConfig
 from .shapes import LONG_CONTEXT_WINDOW, SHAPES, InputShape
 
-from . import (chameleon_34b, internlm2_1_8b, jamba_1_5_large, llama3_2_1b,
-               llama4_maverick, moonshot_v1_16b, musicgen_medium,
+from . import (chameleon_34b, internlm2_1_8b, jamba2_3b, jamba_1_5_large,
+               llama3_2_1b, llama4_maverick, moonshot_v1_16b, musicgen_medium,
                phi4_mini_3_8b, qwen3_moe_30b, xlstm_125m)
 
 REGISTRY: dict[str, ArchConfig] = {
     m.CONFIG.name: m.CONFIG for m in (
         musicgen_medium, jamba_1_5_large, xlstm_125m, chameleon_34b,
         llama3_2_1b, internlm2_1_8b, moonshot_v1_16b, phi4_mini_3_8b,
-        qwen3_moe_30b, llama4_maverick,
+        qwen3_moe_30b, llama4_maverick, jamba2_3b,
     )
 }
 
@@ -35,6 +35,7 @@ ALIASES = {
     "phi4-mini-3.8b": "phi4-mini-3.8b",
     "qwen3-moe-30b-a3b": "qwen3-moe-30b-a3b",
     "llama4-maverick-400b-a17b": "llama4-maverick-400b-a17b",
+    "jamba2-3b": "jamba2-3b",
 }
 
 

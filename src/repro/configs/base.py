@@ -10,6 +10,9 @@ Every assigned architecture is expressed as an ``ArchConfig``:
   with ``layer_idx % moe.every_k_layers == moe.offset``.
 * ``embeds_input`` — audio/vlm frontends are stubs: training consumes
   precomputed frame/patch embeddings of shape (B, S, d_model).
+* ``rope`` off makes attention position-free (NoPE, as Jamba's);
+  ``ssm_inner_norm`` RMS-normalizes Mamba's Δ, B and C inputs (Jamba);
+  ``compute_dtype`` carries activations in another dtype than the weights.
 """
 from __future__ import annotations
 
@@ -50,6 +53,9 @@ class ArchConfig:
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_expand: int = 2
+    ssm_inner_norm: bool = False        # RMSNorm on Δ, B, C after x_proj
+    rope: bool = True                   # False: NoPE attention
+    compute_dtype: str = ""             # activations; "" ⇒ ``dtype``
     citation: str = ""
     notes: str = ""
 

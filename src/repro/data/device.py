@@ -54,7 +54,8 @@ class DeviceDataStore(NamedTuple):
     """
 
     x: jax.Array        # [K, N_max, ...] inputs, zero-padded
-    y: jax.Array        # [K, N_max] int32 labels, zero-padded
+    y: jax.Array        # [K, N_max, ...] int32 labels (a class, or a
+                        # sequence of target tokens), zero-padded
     lengths: jax.Array  # [K] int32 valid example counts
 
     @property
@@ -83,7 +84,8 @@ def _pack_clients(clients: Sequence[Dataset],
                   pad_to: int | None = None):
     """Host-side pad-and-pack shared by the device store and the streaming
     sampler (one implementation ⇒ the two paths stay bit-identical):
-    ``(x [K, cap, ...], y [K, cap], counts [K])`` as numpy arrays."""
+    ``(x [K, cap, ...], y [K, cap, ...], counts [K])`` as numpy arrays; a
+    label keeps its trailing shape (``[S]`` target tokens per sequence)."""
     counts = [int(np.asarray(c.y).shape[0]) for c in clients]
     if min(counts) == 0:
         raise ValueError("every client shard must be non-empty")
@@ -93,7 +95,7 @@ def _pack_clients(clients: Sequence[Dataset],
     sample = np.asarray(clients[0].x).shape[1:]
     K = len(clients)
     x = np.zeros((K, cap) + sample, np.asarray(clients[0].x).dtype)
-    y = np.zeros((K, cap), np.int32)
+    y = np.zeros((K, cap) + np.asarray(clients[0].y).shape[1:], np.int32)
     for k, c in enumerate(clients):
         x[k, : counts[k]] = np.asarray(c.x)
         y[k, : counts[k]] = np.asarray(c.y)

@@ -61,6 +61,8 @@ def param_count(cfg: ArchConfig) -> int:
         elif mixer == "mamba":
             total += (d * 2 * di + k * di + di + di * (dtr + 2 * N)
                       + dtr * di + di + di * N + di + di * d)
+            if cfg.ssm_inner_norm:
+                total += dtr + 2 * N
         elif mixer == "mlstm":
             total += 5 * d * d + 2 * d * H  # q,k,v,o-gate,out + i/f gates
         elif mixer == "slstm":

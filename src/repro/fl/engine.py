@@ -240,16 +240,18 @@ def round_decision(policy_fn: PolicyFn, t: jax.Array, h_t: jax.Array,
 
 
 def make_local_train(loss_fn: Callable, opt: Optimizer):
-    """vmapped-over-clients local SGD: ``(params, xb, yb) -> params`` with
-    ``xb: [K, local_iters, B, ...]``."""
+    """vmapped-over-clients local SGD: ``(params, xb, yb, *frozen) -> params``
+    with ``xb: [K, local_iters, B, ...]``.  A ``frozen`` pytree (a model part
+    no client trains, such as the base under LoRA adapters) goes to
+    ``loss_fn(params, x, y, frozen)`` unbatched and gets no gradient."""
 
-    def local_train(params, xb, yb):
+    def local_train(params, xb, yb, *frozen):
         opt_state = opt.init(params)
 
         def one(carry, batch):
             params, opt_state = carry
             x, y = batch
-            g = jax.grad(loss_fn)(params, x, y)
+            g = jax.grad(loss_fn)(params, x, y, *frozen)
             upd, opt_state = opt.update(g, opt_state, params)
             params = jax.tree_util.tree_map(lambda p, u: p + u, params, upd)
             return (params, opt_state), None
@@ -257,7 +259,11 @@ def make_local_train(loss_fn: Callable, opt: Optimizer):
         (params, _), _ = jax.lax.scan(one, (params, opt_state), (xb, yb))
         return params
 
-    return jax.vmap(local_train)
+    def vtrain(params, xb, yb, *frozen):
+        return jax.vmap(local_train, in_axes=(0, 0, 0) + (None,) * len(frozen)
+                        )(params, xb, yb, *frozen)
+
+    return vtrain
 
 
 def empty_client_batches(client_data: Sequence[Dataset], cfg: SimConfig):
@@ -378,7 +384,7 @@ def _make_round_step(vtrain: Callable, loss_fn: Callable, acc_fn: Callable,
                          "(expected inscan|replay)")
 
     def round_step(carry, t, h_t, xb, yb, pw, base_key, test_x, test_y,
-                   fp=None, ap=None):
+                   fp=None, ap=None, frozen=()):
         state, energy = carry[0], carry[1]
         if faults is not None:
             fstate = carry[2]
@@ -403,7 +409,7 @@ def _make_round_step(vtrain: Callable, loss_fn: Callable, acc_fn: Callable,
             corrupt = jnp.zeros((K,), bool)
         energy = energy + e_round
         # --- Step 1 (local training) + Steps 4-5 ---------------------------
-        client = vtrain(state.client_params, xb, yb)
+        client = vtrain(state.client_params, xb, yb, *frozen)
         if cfg.local_mode == "participants":
             # only clients whose update lands move; everyone else keeps
             # client == anchor (their pseudo-gradient stays exactly zero —
@@ -461,8 +467,10 @@ def _make_round_step(vtrain: Callable, loss_fn: Callable, acc_fn: Callable,
             do_eval = jnp.zeros((), bool)
         else:
             def eval_now(p):
-                return (jnp.asarray(acc_fn(p, test_x, test_y), jnp.float32),
-                        jnp.asarray(loss_fn(p, test_x, test_y), jnp.float32))
+                return (jnp.asarray(acc_fn(p, test_x, test_y, *frozen),
+                                    jnp.float32),
+                        jnp.asarray(loss_fn(p, test_x, test_y, *frozen),
+                                    jnp.float32))
 
             def skip_eval(p):
                 del p
@@ -504,6 +512,10 @@ def build_scan_sim(loss_fn: Callable, acc_fn: Callable, opt: Optimizer,
     RoundTrace[T])`` and is traceable end-to-end: jit it for a single run,
     vmap it over ``(base_key, h_rounds)`` (and a traced ρ via the policy
     closure) for scenario fan-out.  ``h_rounds`` is round-major ``[T, K]``.
+    Its ``frozen`` keyword takes a pytree that every client's
+    ``loss_fn``/``acc_fn`` receives as a fourth argument and nobody trains
+    (a LoRA base); the FL state, the aggregation and the ledger see only
+    ``params``.
 
     Policies tagged ``state_free`` (all five paper schemes) are hoisted out
     of the sequential scan: every round's ``(probs, w)`` is computed in one
@@ -526,9 +538,13 @@ def build_scan_sim(loss_fn: Callable, acc_fn: Callable, opt: Optimizer,
     mesh = _client_mesh(K) if shard_clients in (None, True) else None
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        vtrain = jax.shard_map(vtrain, mesh=mesh,
-                               in_specs=(P("k"), P("k"), P("k")),
-                               out_specs=P("k"))
+        local = vtrain
+
+        def vtrain(params, xb, yb, *frozen):   # frozen is replicated
+            return jax.shard_map(
+                local, mesh=mesh,
+                in_specs=(P("k"), P("k"), P("k")) + (P(),) * len(frozen),
+                out_specs=P("k"))(params, xb, yb, *frozen)
     # XLA cannot partition a Pallas kernel over the sharded client axis
     # ("Mosaic kernels cannot be automatically partitioned"), so a sharded
     # run aggregates with the jnp path: a per-device fused reduce of its
@@ -570,26 +586,30 @@ def build_scan_sim(loss_fn: Callable, acc_fn: Callable, opt: Optimizer,
 
     if data_mode == "prestack":
         def simulate(params, xb_all, yb_all, h_rounds, base_key, test_x,
-                     test_y, pw_all=None, fault_params=None, agg_params=None):
+                     test_y, pw_all=None, fault_params=None, agg_params=None,
+                     frozen=None):
             ts_all = jnp.arange(cfg.rounds, dtype=jnp.int32)
             pw_all = _resolve_pw(h_rounds, pw_all)
             fp = _resolve_fp(fault_params)
             ap = _resolve_ap(agg_params)
+            fz = () if frozen is None else (frozen,)
 
             def step(carry, xs):
                 t, h_t, xb, yb, pw = xs
                 return round_step(carry, t, h_t, xb, yb, pw, base_key,
-                                  test_x, test_y, fp=fp, ap=ap)
+                                  test_x, test_y, fp=fp, ap=ap, frozen=fz)
 
             return _scan(params, step, (ts_all, h_rounds, xb_all, yb_all,
                                         pw_all))
     elif data_mode == "device":
         def simulate(params, store, data_key, h_rounds, base_key, test_x,
-                     test_y, pw_all=None, fault_params=None, agg_params=None):
+                     test_y, pw_all=None, fault_params=None, agg_params=None,
+                     frozen=None):
             ts_all = jnp.arange(cfg.rounds, dtype=jnp.int32)
             pw_all = _resolve_pw(h_rounds, pw_all)
             fp = _resolve_fp(fault_params)
             ap = _resolve_ap(agg_params)
+            fz = () if frozen is None else (frozen,)
 
             sample = (sample_round_client_stream
                       if cfg.data_stream == "client" else sample_round)
@@ -599,7 +619,7 @@ def build_scan_sim(loss_fn: Callable, acc_fn: Callable, opt: Optimizer,
                 xb, yb = sample(store, data_key, t, cfg.local_iters,
                                 cfg.batch_size)
                 return round_step(carry, t, h_t, xb, yb, pw, base_key,
-                                  test_x, test_y, fp=fp, ap=ap)
+                                  test_x, test_y, fp=fp, ap=ap, frozen=fz)
 
             return _scan(params, step, (ts_all, h_rounds, pw_all))
     else:
@@ -681,6 +701,11 @@ def build_chunk_sim(loss_fn: Callable, acc_fn: Callable, opt: Optimizer,
 
     chunk.hoist = hoist
     return chunk
+
+
+def _nbytes(tree) -> int:
+    return sum(int(x.size) * np.dtype(x.dtype).itemsize
+               for x in jax.tree_util.tree_leaves(tree))
 
 
 def _to_host(x) -> np.ndarray:
@@ -827,7 +852,8 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
                 opt: Optimizer | None = None,
                 shard_clients: bool | None = None,
                 data_path: str | None = None,
-                data_budget_bytes: int | None = None) -> Callable:
+                data_budget_bytes: int | None = None,
+                frozen: Any = None) -> Callable:
     """Pre-build the compiled scan runner for repeated invocations.
 
     Returns ``runner(params, h_all, seed=None) -> SimResult``; the jitted
@@ -841,12 +867,25 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
     store's client axis is placed on the same mesh as the FL state whenever
     client-axis sharding is active.
 
+    ``frozen`` is a pytree every client's model reads and nobody trains,
+    such as the base model under LoRA adapters: ``loss_fn`` and ``acc_fn``
+    then take it as a fourth argument, ``params`` holds only what clients
+    train, and the FL state, the aggregation, the eq.-5 ledger and the
+    packed record see only ``params``.  It goes into the program as an
+    argument of every call (never baked into the executable).  The dense
+    device and prestack paths take it; the sparse and streaming paths do
+    not yet.
+
     The dense scan runner crosses the host boundary once each way per call:
     the seed and ``h_all`` go into its program as host values (no eager
     device work before the launch), and every array the ``SimResult`` reads
     comes back in one packed ``uint32`` record, copied once and unpacked
     bit for bit on the host; ``SimResult.state`` stays on the device.  It
-    also carries ``runner.call_args(params, h_all, seed=None)`` and
+    counts, per call, ``engine.train_tokens`` (K·T·L·B times the positions
+    of one label: tokens for sequence targets, examples for class labels)
+    and ``engine.upload_bytes`` (realized uploads × the bytes of
+    ``params``).  It also carries ``runner.call_args(params, h_all,
+    seed=None)`` and
     ``runner.lower(params, h_all, seed=None)`` — its program's arguments,
     and its program lowered, for that call — and ``runner.mesh``, the
     client-axis mesh (``None`` when unsharded).
@@ -859,6 +898,9 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
 
     from .sparse import make_sparse_runner, resolve_participation
     if resolve_participation(cfg, policy_fn, path, K) == "sparse":
+        if frozen is not None:
+            raise ValueError("the sparse runner does not take a frozen model "
+                             "part; use participation='dense'")
         # opt passed un-defaulted: the sparse runner tokens the default
         # optimizer by (kind, lr) so its participant-program cache hits
         # across runners (a fresh sgd() closure per call would miss on id)
@@ -869,6 +911,9 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
                       extra={"path": path, "num_clients": K})
 
     if path == "stream":
+        if frozen is not None:
+            raise ValueError("the streaming runner does not take a frozen "
+                             "model part; use data_path='device'")
         return _make_stream_runner(loss_fn, acc_fn, client_data, test_x,
                                    test_y, policy_fn, cell, cfg, opt)
 
@@ -876,13 +921,13 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
                          shard_clients=shard_clients, data_mode=path)
     tapped = metrics_active(cfg.metrics, cfg.guards)
 
-    def program(params, data, h_all, seed, test_x, test_y, pw_all):
+    def program(params, data, h_all, seed, test_x, test_y, pw_all, *frozen):
         """One call on the device: the key and the round-major channel are
         made here from the raw host values, and what the host reads comes
         back as one packed record beside the device-resident state."""
         key = jax.random.PRNGKey(seed)
         out = sim(params, *data, jnp.swapaxes(h_all, 0, 1), key, test_x,
-                  test_y, pw_all=pw_all)
+                  test_y, pw_all=pw_all, frozen=frozen[0] if frozen else None)
         return out[0], _pack(_host_fields(out[1], out[2], cfg,
                                           out[3] if tapped else None))
 
@@ -907,7 +952,11 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
         bits); ``h_all`` goes in as given."""
         seed = np.uint32(int(cfg.seed if seed is None else seed) % 2 ** 32)
         pw = policy_pre(h_all) if policy_pre is not None else None
-        return params, data, h_all, seed, test_x, test_y, pw
+        return (params, data, h_all, seed, test_x, test_y, pw) + fz
+
+    fz = () if frozen is None else (frozen,)
+    tokens_per_call = (K * cfg.rounds * cfg.local_iters * cfg.batch_size
+                       * math.prod(np.shape(client_data[0].y)[1:]))
 
     def runner(params, h_all, seed: int | None = None) -> SimResult:
         tel = get_telemetry()
@@ -919,7 +968,13 @@ def make_runner(loss_fn: Callable, acc_fn: Callable,
             with tel.span("engine.wait"):   # the one copy waits for the device
                 words = _to_host(record.words)
             with tel.span("engine.readback"):
-                return _host_result(state, _unpack(words, record.layout))
+                res = _host_result(state, _unpack(words, record.layout))
+                up = (res.participation if res.delivered is None
+                      else res.delivered)
+                tel.inc("engine.train_tokens", tokens_per_call)
+                tel.inc("engine.upload_bytes",
+                        int(np.sum(up)) * _nbytes(params))
+                return res
 
     def lower(params, h_all, seed: int | None = None):
         """The runner's jitted program, lowered for exactly this call."""

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
 from .costmode import cost_mode
-from .layers import apply_rope, dense_init, rms_norm
+from .layers import apply_rope, dense_init, mm, rms_norm
 
 NEG_INF = -1e30
 
@@ -47,14 +47,15 @@ def init_cache(cfg: ArchConfig, batch: int, capacity: int, dtype) -> KVCache:
 def _qkv(p, cfg: ArchConfig, x, positions):
     B, S, _ = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    q = (x @ p["wq"]).reshape(B, S, H, hd)
-    k = (x @ p["wk"]).reshape(B, S, KV, hd)
-    v = (x @ p["wv"]).reshape(B, S, KV, hd)
+    q = mm(x, p["wq"]).reshape(B, S, H, hd)
+    k = mm(x, p["wk"]).reshape(B, S, KV, hd)
+    v = mm(x, p["wv"]).reshape(B, S, KV, hd)
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope:               # NoPE models (Jamba) carry no position here
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -134,6 +135,9 @@ def _attend_chunked(q, k, v, cfg: ArchConfig, q_chunk: int = 512,
         out = acc / jnp.maximum(l, 1e-30)[..., None]     # [B,KV,G,qc,hd]
         return out.transpose(0, 3, 1, 2, 4)              # [B,qc,KV,G,hd]
 
+    # rematerialized per query block: a backward pass holds one block's
+    # [B,KV,G,qc,kc] scores at a time, not every block's
+    q_block = jax.checkpoint(q_block)
     out = jax.lax.map(lambda args: q_block(*args),
                       (jnp.arange(nq), qg.transpose(1, 0, 2, 3, 4, 5)))
     out = out.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H, hd)
@@ -145,7 +149,7 @@ def attn_forward(p, cfg: ArchConfig, x, positions):
     B, S, _ = x.shape
     q, k, v = _qkv(p, cfg, x, positions)
     out = _attend_chunked(q, k, v, cfg)
-    return (out.reshape(B, S, -1).astype(x.dtype) @ p["wo"]).astype(x.dtype)
+    return mm(out.reshape(B, S, -1).astype(x.dtype), p["wo"]).astype(x.dtype)
 
 
 def attn_prefill(p, cfg: ArchConfig, x, positions, capacity: int):
@@ -153,7 +157,7 @@ def attn_prefill(p, cfg: ArchConfig, x, positions, capacity: int):
     B, S, _ = x.shape
     q, k, v = _qkv(p, cfg, x, positions)
     out = _attend_chunked(q, k, v, cfg)
-    y = (out.reshape(B, S, -1).astype(x.dtype) @ p["wo"]).astype(x.dtype)
+    y = mm(out.reshape(B, S, -1).astype(x.dtype), p["wo"]).astype(x.dtype)
     if capacity >= S:
         ck = jnp.pad(k, ((0, 0), (0, capacity - S), (0, 0), (0, 0)))
         cv = jnp.pad(v, ((0, 0), (0, capacity - S), (0, 0), (0, 0)))
@@ -177,5 +181,5 @@ def attn_decode(p, cfg: ArchConfig, x, cache: KVCache):
     idx = jnp.arange(C)
     valid = idx <= jnp.minimum(cache.pos, C - 1)        # filled slots (ring ⇒ all
     out = _attend(scores, cv, valid[None, None, None, None])  # once pos ≥ C)
-    y = (out.reshape(B, 1, -1) @ p["wo"]).astype(x.dtype)
+    y = mm(out.reshape(B, 1, -1), p["wo"]).astype(x.dtype)
     return y, KVCache(k=ck, v=cv, pos=cache.pos + 1)

@@ -1,8 +1,19 @@
-"""Shared neural building blocks: RMSNorm, RoPE, SwiGLU, initializers."""
+"""Shared neural building blocks: RMSNorm, RoPE, SwiGLU, initializers, and
+``mm``, the projection every layer multiplies its weights with."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from .lora import LoraWeight, matmul as lora_matmul
+
+
+def mm(x: jax.Array, w) -> jax.Array:
+    """``x @ w``; a :class:`~repro.models.lora.LoraWeight` adds its
+    adapter's low-rank product."""
+    if isinstance(w, LoraWeight):
+        return lora_matmul(x, w)
+    return x @ w
 
 
 def dense_init(key, n_in, n_out, dtype=jnp.float32):
@@ -36,8 +47,8 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 def swiglu(x: jax.Array, w1: jax.Array, w3: jax.Array,
            w2: jax.Array) -> jax.Array:
-    h = jax.nn.silu(x @ w1) * (x @ w3)
-    return h @ w2
+    h = jax.nn.silu(mm(x, w1)) * mm(x, w3)
+    return mm(h, w2)
 
 
 def init_swiglu(key, d, ff, dtype):

@@ -1,15 +1,21 @@
 """Mamba (S6 selective-state-space) block in pure JAX.
 
 Train/prefill use ``jax.lax.associative_scan`` over the sequence (log-depth on
-TPU); decode is the O(1) recurrence.  The recurrence per channel c and state n:
+TPU), inside the jitted :func:`mamba_scan` so that its device ops carry that
+name in a profiler trace; decode is the O(1) recurrence.  The recurrence per
+channel c and state n:
 
     h_t = exp(Δ_t·A) ⊙ h_{t-1} + (Δ_t·B_t)·x_t
     y_t = C_t·h_t + D ⊙ x_t
+
+With ``cfg.ssm_inner_norm`` (Jamba) the Δ, B and C inputs from ``x_proj``
+each pass an RMSNorm before use.
 
 Cache: (conv tail [B, k-1, di], ssm state [B, di, N]).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple
 
@@ -17,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from .layers import dense_init
+from .layers import dense_init, mm, rms_norm
 from .costmode import cost_mode
 from .pshard import shard_dim, shard_last
 
@@ -42,7 +48,7 @@ def init_mamba(key, cfg: ArchConfig, dtype):
     dt = jnp.exp(jax.random.uniform(ks[0], (di,), jnp.float32)
                  * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
     dt_bias = dt + jnp.log1p(-jnp.exp(-dt))  # inverse softplus
-    return {
+    p = {
         "in_proj": dense_init(ks[1], d, 2 * di, dtype),
         "conv_w": (jax.random.normal(ks[2], (k, di), jnp.float32)
                    / jnp.sqrt(float(k))).astype(dtype),
@@ -54,6 +60,10 @@ def init_mamba(key, cfg: ArchConfig, dtype):
         "D": jnp.ones((di,), jnp.float32),
         "out_proj": dense_init(ks[5], di, d, dtype),
     }
+    if cfg.ssm_inner_norm:
+        p.update(dt_norm=jnp.ones((dt_rank,), dtype),
+                 b_norm=jnp.ones((N,), dtype), c_norm=jnp.ones((N,), dtype))
+    return p
 
 
 def _ssm_inputs(p, cfg: ArchConfig, xc):
@@ -63,8 +73,12 @@ def _ssm_inputs(p, cfg: ArchConfig, xc):
     proj = xc @ p["x_proj"]
     dt_in, Bc, Cc = jnp.split(proj.astype(jnp.float32),
                               [dt_rank, dt_rank + N], axis=-1)
+    if cfg.ssm_inner_norm:
+        dt_in = rms_norm(dt_in, p["dt_norm"], cfg.norm_eps)
+        Bc = rms_norm(Bc, p["b_norm"], cfg.norm_eps)
+        Cc = rms_norm(Cc, p["c_norm"], cfg.norm_eps)
     dt = jax.nn.softplus(dt_in @ p["dt_proj"] + p["dt_bias"])   # [B,S,di]
-    A = -jnp.exp(p["A_log"])                                    # [di,N]
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))               # [di,N]
     dA = jnp.exp(dt[..., None] * A)                             # [B,S,di,N]
     dBx = (dt[..., None] * Bc[..., None, :]) * xc.astype(jnp.float32)[..., None]
     return dA, dBx, Cc
@@ -90,27 +104,26 @@ def _combine(a, b):
     return a1 * a2, a2 * b1 + b2
 
 
-def mamba_forward(p, cfg: ArchConfig, x, return_cache=False,
-                  chunk: int = 128):
-    """x: [B,S,d] → y [B,S,d] (+ cache).
+#: the parameters the selective scan reads (the projections around it are
+#: applied outside :func:`mamba_scan`)
+SCAN_KEYS = ("x_proj", "dt_proj", "dt_bias", "A_log", "D", "dt_norm",
+             "b_norm", "c_norm")
 
-    The selective scan is *chunked*: a sequential ``lax.scan`` over S/chunk
-    blocks carrying the [B,di,N] state, with a log-depth associative scan
-    inside each block.  Never materializes the full [B,S,di,N] tensor
-    (68 TB for Jamba at 32k) — peak is O(B·chunk·di·N).
+
+@functools.partial(jax.jit, static_argnames=("cfg", "chunk"))
+def mamba_scan(p, cfg: ArchConfig, xc, chunk: int):
+    """The selective scan: post-conv activations ``xc [B,S,di]`` →
+    ``(y [B,S,di] f32, last state [B,di,N])``.
+
+    *Chunked*: a sequential ``lax.scan`` over S/chunk blocks carrying the
+    [B,di,N] state, with a log-depth associative scan inside each block.
+    Never materializes the full [B,S,di,N] tensor (68 TB for Jamba at 32k) —
+    peak is O(B·chunk·di·N).
     """
-    B, S, d = x.shape
-    di, dt_rank, N, k = _dims(cfg)
-    xz = shard_last(x @ p["in_proj"])
-    xin, z = jnp.split(xz, 2, axis=-1)
-    xc, tail = _conv(p, xin, cfg)
-    xc = shard_last(xc)
-
-    c = min(chunk, S)
-    if S % c != 0 or cost_mode():  # ragged/test shapes or cost probe
-        c = S
-    nb = S // c
-    xcb = xc.reshape(B, nb, c, di).transpose(1, 0, 2, 3)   # [nb,B,c,di]
+    B, S, di = xc.shape
+    N = cfg.ssm_state
+    nb = S // chunk
+    xcb = xc.reshape(B, nb, chunk, di).transpose(1, 0, 2, 3)  # [nb,B,c,di]
 
     @jax.checkpoint
     def block(h0, xc_blk):
@@ -130,9 +143,28 @@ def mamba_forward(p, cfg: ArchConfig, x, return_cache=False,
 
     h0 = shard_dim(jnp.zeros((B, di, N), jnp.float32), 1)
     h_last, yb = jax.lax.scan(block, h0, xcb)
-    y = shard_last(yb.transpose(1, 0, 2, 3).reshape(B, S, di))
+    return yb.transpose(1, 0, 2, 3).reshape(B, S, di), h_last
+
+
+def mamba_forward(p, cfg: ArchConfig, x, return_cache=False,
+                  chunk: int = 64):
+    """x: [B,S,d] → y [B,S,d] (+ cache); the scan is :func:`mamba_scan`,
+    ``chunk`` tokens a step (its [B,chunk,di,N] levels set the scan's
+    memory)."""
+    B, S, d = x.shape
+    xz = shard_last(mm(x, p["in_proj"]))
+    xin, z = jnp.split(xz, 2, axis=-1)
+    xc, tail = _conv(p, xin, cfg)
+    xc = shard_last(xc)
+
+    c = min(chunk, S)
+    if S % c != 0 or cost_mode():  # ragged/test shapes or cost probe
+        c = S
+    y, h_last = mamba_scan({k: p[k] for k in SCAN_KEYS if k in p}, cfg, xc,
+                           chunk=c)
+    y = shard_last(y)
     y = y.astype(x.dtype) * jax.nn.silu(z)
-    out = y @ p["out_proj"]
+    out = mm(y, p["out_proj"])
     if return_cache:
         return out, MambaCache(conv=tail, ssm=h_last)
     return out
@@ -147,11 +179,11 @@ def init_mamba_cache(cfg: ArchConfig, batch: int, dtype) -> MambaCache:
 def mamba_decode(p, cfg: ArchConfig, x, cache: MambaCache):
     """One-token step.  x: [B,1,d]."""
     B = x.shape[0]
-    xz = x @ p["in_proj"]
+    xz = mm(x, p["in_proj"])
     xin, z = jnp.split(xz, 2, axis=-1)
     xc, tail = _conv(p, xin, cfg, tail=cache.conv)
     dA, dBx, Cc = _ssm_inputs(p, cfg, xc)            # S = 1
     h = dA[:, 0] * cache.ssm + dBx[:, 0]             # [B,di,N]
     y = jnp.einsum("bdn,bn->bd", h, Cc[:, 0]) + p["D"] * xc[:, 0].astype(jnp.float32)
     y = y[:, None].astype(x.dtype) * jax.nn.silu(z)
-    return y @ p["out_proj"], MambaCache(conv=tail, ssm=h)
+    return mm(y, p["out_proj"]), MambaCache(conv=tail, ssm=h)

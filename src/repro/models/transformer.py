@@ -8,7 +8,8 @@ Jamba compiles the same graph as an 8-layer one).
 Entry points (all pure):
   init_params(key, cfg)
   forward(params, cfg, tokens|embeds)              → logits          (train)
-  loss(params, cfg, batch)                         → (scalar, aux)
+  loss(params, cfg, batch)                         → scalar (CE + aux)
+  accuracy(params, cfg, batch)                     → share of top-1 hits
   prefill(params, cfg, tokens|embeds, capacity)    → (logits, caches)
   decode_step(params, cfg, token, caches)          → (logits, caches)
   init_caches(cfg, batch, capacity, dtype)
@@ -24,12 +25,18 @@ import jax.numpy as jnp
 from ..configs.base import ArchConfig
 from . import attention, mamba, moe, xlstm
 from .costmode import cost_mode
-from .layers import dense_init, init_swiglu, rms_norm, swiglu
+from .layers import dense_init, init_swiglu, mm, rms_norm, swiglu
+
+_DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
+           "float16": jnp.float16}
 
 
 def _dtype(cfg: ArchConfig):
-    return {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
-            "float16": jnp.float16}[cfg.dtype]
+    return _DTYPES[cfg.dtype]
+
+
+def _act_dtype(cfg: ArchConfig):
+    return _DTYPES[cfg.compute_dtype or cfg.dtype]
 
 
 # ---------------------------------------------------------------------------
@@ -198,6 +205,11 @@ def _scan_blocks(params, cfg: ArchConfig, x, positions, mode: str,
     if caches is None:
         from .pshard import shard_dim
 
+        def layer(lp, x, mixer, ffn):
+            x, _, a = _apply_layer(lp, cfg, mixer, ffn, x, positions, mode,
+                                   None, capacity)
+            return x, a
+
         def body(carry, bp):
             x, aux = carry
             # sequence parallelism at super-block boundaries (§Perf iter 7):
@@ -206,16 +218,16 @@ def _scan_blocks(params, cfg: ArchConfig, x, positions, mode: str,
             # all-gathers/reduce-scatters around each block as needed.
             x = shard_dim(x, -2, "model")
             for i, (mixer, ffn) in enumerate(plan):
-                x, _, a = _apply_layer(bp[i], cfg, mixer, ffn, x, positions,
-                                       mode, None, capacity)
+                f = partial(layer, mixer=mixer, ffn=ffn)
+                if mode == "train":
+                    # activation checkpointing per layer: backward keeps
+                    # each layer's input and rebuilds one layer's
+                    # intermediates at a time
+                    f = jax.checkpoint(f)
+                x, a = f(bp[i], x)
                 aux = aux + a
             return (x, aux), None
 
-        if mode == "train":
-            # activation checkpointing per super-block: backward recomputes
-            # the block instead of keeping every intermediate of the scan
-            body = jax.checkpoint(body,
-                                  policy=jax.checkpoint_policies.nothing_saveable)
         (x, aux), _ = jax.lax.scan(body, (x, aux0), params["blocks"])
         return x, aux, None
 
@@ -241,8 +253,9 @@ def _scan_blocks(params, cfg: ArchConfig, x, positions, mode: str,
 
 def _embed(params, cfg: ArchConfig, tokens=None, embeds=None):
     if embeds is not None:
-        return embeds.astype(_dtype(cfg))
-    return jnp.take(params["embed"], tokens, axis=0)
+        return embeds.astype(_act_dtype(cfg))
+    x = jnp.take(params["embed"], tokens, axis=0)
+    return x.astype(_act_dtype(cfg)) if cfg.compute_dtype else x
 
 
 def _logits(params, cfg: ArchConfig, x):
@@ -267,36 +280,82 @@ def forward(params, cfg: ArchConfig, tokens=None, embeds=None):
     return _logits(params, cfg, x), aux
 
 
-def loss(params, cfg: ArchConfig, batch):
-    """Next-token (or labeled) cross-entropy + MoE aux loss.
-
-    batch: {"tokens": [B,S]} or {"embeds": [B,S,d], "labels": [B,S]}.
-
-    Vocab-parallel CE (§Perf iteration 5): the next-token shift happens on
-    the *hidden* states (d-wide) before the unembed matmul, and the loss is
-    ``logsumexp(logits) − logits[target]`` computed directly — the vocab
-    dim stays sharded end-to-end; only [B,S,1]-sized reductions cross the
-    mesh instead of fp32 [B,S,V] normalized-logit reshards.
-    """
-    from .pshard import shard_last
+def _batch_hidden(params, cfg: ArchConfig, batch):
+    """Final-normed hidden states and their targets of ``batch``: its
+    ``labels`` where given, else the next token of ``tokens``."""
     if "embeds" in batch:
         x, aux = forward_hidden(params, cfg, embeds=batch["embeds"])
+    else:
+        x, aux = forward_hidden(params, cfg, tokens=batch["tokens"])
+    if "labels" in batch:
         targets = batch["labels"]
     else:
-        tokens = batch["tokens"]
-        x, aux = forward_hidden(params, cfg, tokens=tokens)
-        x = x[:, :-1]
-        targets = tokens[:, 1:]
-    logits = shard_last(_logits(params, cfg, x))       # [B,S',V] V-sharded
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    # target pick as a contraction over the sharded vocab dim (a gather
-    # would make GSPMD replicate the fp32 logits; the one-hot dot keeps V
-    # sharded and all-reduces only [B,S]-sized partials)
-    onehot = jax.nn.one_hot(targets, cfg.vocab, dtype=logits.dtype)
-    tgt = jnp.einsum("bsv,bsv->bs", logits, onehot)
-    ce = jnp.mean(lse - tgt)
-    w = cfg.moe.aux_loss_weight if cfg.moe is not None else 0.0
-    return ce + w * aux
+        # the next-token shift happens on the d-wide hidden states, before
+        # the unembedding
+        x, targets = x[:, :-1], batch["tokens"][:, 1:]
+    return rms_norm(x, params["final_norm"], cfg.norm_eps), targets, aux
+
+
+def _head_sum(params, cfg: ArchConfig, x, targets, accuracy: bool,
+              chunk: int = 128):
+    """Sum over positions of the cross-entropy (or, with ``accuracy``, of
+    the hits of the largest logit) of ``targets [B,S]`` under hidden states
+    ``x [B,S,d]``.
+
+    The unembedding runs ``chunk`` positions at a time inside a
+    checkpointed scan, so no [B,S,V] logit or gradient array exists when S
+    is a multiple of ``chunk`` (else the S positions are one chunk).  The
+    vocabulary dim stays sharded (§Perf iteration 5): the target's logit is
+    picked by a masked sum over it, so only [B,chunk]-sized reductions
+    cross the mesh, and the pick is exact at any matmul precision.
+    """
+    from .pshard import shard_last
+
+    B, S, d = x.shape
+    c = chunk if S % chunk == 0 else S
+    xs = x.reshape(B, S // c, c, d).swapaxes(0, 1)          # [n,B,c,d]
+    ts = targets.reshape(B, S // c, c).swapaxes(0, 1)       # [n,B,c]
+
+    @jax.checkpoint
+    def one(total, xt):
+        xc, tc = xt
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bcd,vd->bcv", xc, params["embed"])
+        else:
+            logits = mm(xc, params["unembed"])
+        logits = shard_last(logits.astype(jnp.float32))     # [B,c,V]
+        if accuracy:
+            v = jnp.sum(jnp.argmax(logits, -1) == tc)
+        else:
+            hit = jnp.arange(logits.shape[-1]) == tc[..., None]
+            tgt = jnp.sum(jnp.where(hit, logits, 0.0), -1)
+            v = jnp.sum(jax.scipy.special.logsumexp(logits, -1) - tgt)
+        return total + v.astype(jnp.float32), None
+
+    total, _ = jax.lax.scan(one, jnp.zeros((), jnp.float32), (xs, ts))
+    return total
+
+
+def loss(params, cfg: ArchConfig, batch):
+    """Mean cross-entropy + MoE aux loss.
+
+    batch: {"tokens": [B,S]} (next-token targets), optionally with
+    {"labels": [B,S]}, or {"embeds": [B,S,d], "labels": [B,S]}.
+    """
+    x, targets, aux = _batch_hidden(params, cfg, batch)
+    ce = _head_sum(params, cfg, x, targets, accuracy=False) / targets.size
+    if cfg.moe is None:
+        # no aux term: the forward is then the one ``accuracy`` runs on the
+        # same batch, and XLA computes it once for both
+        return ce
+    return ce + cfg.moe.aux_loss_weight * aux
+
+
+def accuracy(params, cfg: ArchConfig, batch):
+    """Share of ``batch``'s positions (as :func:`loss` reads them) whose
+    largest logit is the target."""
+    x, targets, _ = _batch_hidden(params, cfg, batch)
+    return _head_sum(params, cfg, x, targets, accuracy=True) / targets.size
 
 
 def prefill(params, cfg: ArchConfig, tokens=None, embeds=None,

@@ -57,6 +57,41 @@ def test_store_packing_and_masks():
         assert yb[k].min() == k and yb[k].max() == k
 
 
+def test_token_targets_keep_their_sequence_shape():
+    """``[n, S]`` next-token targets pack to ``[K, cap, S]`` and come back
+    whole through the on-device and the streaming samplers."""
+    S = 6
+    clients = [Dataset(jnp.arange(n * S, dtype=jnp.int32).reshape(n, S) + i,
+                       jnp.arange(n * S, dtype=jnp.int32).reshape(n, S)
+                       + 100 * i + 1, 50)
+               for i, n in enumerate((3, 5))]
+    store = from_client_datasets(clients)
+    assert store.x.shape == (2, 5, S) and store.y.shape == (2, 5, S)
+    assert store.y.dtype == jnp.int32
+    for k, c in enumerate(clients):
+        n = c.y.shape[0]
+        np.testing.assert_array_equal(store.y[k, :n], c.y)
+        assert not np.asarray(store.y[k, n:]).any()
+    key = data_stream_key(3)
+    xb, yb = sample_round(store, key, jnp.int32(2), 2, 4)
+    assert yb.shape == (2, 2, 4, S)
+    np.testing.assert_array_equal(yb - xb, np.broadcast_to(
+        (100 * np.arange(2) + 1 - np.arange(2))[:, None, None, None],
+        yb.shape))
+    sx, sy = StreamingSampler(clients, key, 2, 4).chunk(2, 3)
+    np.testing.assert_array_equal(sx[0], xb)
+    np.testing.assert_array_equal(sy[0], yb)
+
+
+def test_class_labels_pack_as_before():
+    """``[n]`` class labels still pack to ``[K, cap]`` int32, zero-padded."""
+    clients = [Dataset(jnp.ones((n, 2)), jnp.arange(n) % 3, 3)
+               for n in (2, 4)]
+    store = from_client_datasets(clients)
+    assert store.y.shape == (2, 4) and store.y.dtype == jnp.int32
+    np.testing.assert_array_equal(store.y, [[0, 1, 0, 0], [0, 1, 2, 0]])
+
+
 def test_store_rejects_empty_client():
     clients = [Dataset(jnp.ones((4, 2)), jnp.zeros((4,), jnp.int32), 2),
                Dataset(jnp.ones((0, 2)), jnp.zeros((0,), jnp.int32), 2)]

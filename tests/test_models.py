@@ -43,6 +43,38 @@ def test_loss_finite_and_grad_flows(name):
         assert float(jnp.abs(g["embed"]).max()) > 0
 
 
+@pytest.mark.parametrize("S", [24, 257])
+def test_loss_and_accuracy_match_the_full_logits(S, name="llama3.2-1b"):
+    """``loss`` and ``accuracy`` unembed 128 positions at a time (S − 1 =
+    256: two chunks; 23: one); they equal the cross-entropy and top-1 hits
+    of ``forward``'s full logits, in value and gradient, and given
+    ``labels`` they read the same targets without the shift."""
+    cfg = tiny(name)
+    key = jax.random.PRNGKey(3)
+    params = T.init_params(key, cfg)
+    tokens = jax.random.randint(key, (2, S), 0, cfg.vocab)
+
+    def full(p):
+        logits, aux = T.forward(p, cfg, tokens)
+        lg, tgt = logits[:, :-1], tokens[:, 1:]
+        pick = jnp.take_along_axis(lg, tgt[..., None], -1)[..., 0]
+        ce = jnp.mean(jax.scipy.special.logsumexp(lg, -1) - pick)
+        w = cfg.moe.aux_loss_weight if cfg.moe is not None else 0.0
+        return ce + w * aux, jnp.mean(jnp.argmax(lg, -1) == tgt)
+
+    (want, want_acc), want_g = jax.value_and_grad(full, has_aux=True)(params)
+    got, got_g = jax.value_and_grad(
+        lambda p: T.loss(p, cfg, {"tokens": tokens}))(params)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(got_g),
+                    jax.tree_util.tree_leaves(want_g)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+    assert float(T.accuracy(params, cfg, {"tokens": tokens})) == \
+        pytest.approx(float(want_acc))
+    labeled = {"tokens": tokens[:, :-1], "labels": tokens[:, 1:]}
+    np.testing.assert_allclose(T.loss(params, cfg, labeled), want, rtol=1e-5)
+
+
 @pytest.mark.parametrize("name", ALL_ARCHS)
 def test_causality(name):
     """Changing future inputs must not affect past logits."""
