@@ -2,9 +2,11 @@
 
 Each kernel ships as <name>.py (pl.pallas_call + explicit BlockSpec VMEM
 tiling) with its jnp oracle in ref.py; ops.py dispatches the aggregation
-kernel, the one on the FL path.  The CPU tests run them in interpret mode;
-``tests/test_chip_compile.py`` compiles ``fl_aggregate`` for a described
-TPU v5e, and ``chip_smoke.py`` runs it on the chip against ref.py.
+kernel, the one on the FL path, and ``models/mamba.py`` runs the selective
+scan (forward and backward) on a TPU.  The CPU tests run them in interpret
+mode; ``tests/test_chip_compile.py`` compiles ``fl_aggregate`` and the
+selective scan for a described TPU v5e, and ``chip_smoke.py`` runs the
+aggregation kernel on the chip against ref.py.
 """
 from . import ops, ref
 from .fl_aggregate import fl_aggregate

@@ -74,10 +74,12 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def selective_scan_ref(xc: jax.Array, dt: jax.Array, Bm: jax.Array,
                        Cm: jax.Array, A: jax.Array,
-                       D: jax.Array) -> jax.Array:
-    """Mamba S6 recurrence (fp32).
+                       D: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Mamba S6 recurrence (fp32), by one associative scan over the
+    expanded state.
 
-    xc, dt: [B, S, d]; Bm, Cm: [B, S, N]; A: [d, N]; D: [d] → y [B, S, d].
+    xc, dt: [B, S, d]; Bm, Cm: [B, S, N]; A: [d, N]; D: [d] → (y [B, S, d],
+    h_last [B, d, N]).
     """
     dA = jnp.exp(dt[..., None] * A)                              # [B,S,d,N]
     dBx = (dt[..., None] * Bm[..., None, :]) * xc[..., None]
@@ -88,4 +90,4 @@ def selective_scan_ref(xc: jax.Array, dt: jax.Array, Bm: jax.Array,
 
     _, h = jax.lax.associative_scan(combine, (dA, dBx), axis=1)
     y = jnp.einsum("bsdn,bsn->bsd", h, Cm) + D * xc
-    return y
+    return y, h[:, -1]

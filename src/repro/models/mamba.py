@@ -1,9 +1,16 @@
-"""Mamba (S6 selective-state-space) block in pure JAX.
+"""Mamba (S6 selective-state-space) block.
 
-Train/prefill use ``jax.lax.associative_scan`` over the sequence (log-depth on
-TPU), inside the jitted :func:`mamba_scan` so that its device ops carry that
-name in a profiler trace; decode is the O(1) recurrence.  The recurrence per
-channel c and state n:
+Train/prefill run the selective scan one of two ways, chosen by
+:func:`scan_kernel_engages` from what the code can observe:
+
+* on a TPU, with the channel width a multiple of 128, outside the cost
+  probe and with no mesh axis over the channels: the Pallas kernels of
+  ``kernels/selective_scan.py`` (:func:`mamba_scan_kernel`), forward and
+  backward, which keep the expanded [B, S, di, N] state in VMEM;
+* everywhere else: ``jax.lax.associative_scan`` over chunks of the sequence
+  (:func:`mamba_scan`), log-depth in each chunk.
+
+Decode is the O(1) recurrence.  The recurrence per channel c and state n:
 
     h_t = exp(Δ_t·A) ⊙ h_{t-1} + (Δ_t·B_t)·x_t
     y_t = C_t·h_t + D ⊙ x_t
@@ -23,9 +30,10 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
+from ..kernels.selective_scan import selective_scan
 from .layers import dense_init, mm, rms_norm
 from .costmode import cost_mode
-from .pshard import shard_dim, shard_last
+from .pshard import axis_size, shard_dim, shard_last
 
 
 class MambaCache(NamedTuple):
@@ -66,9 +74,9 @@ def init_mamba(key, cfg: ArchConfig, dtype):
     return p
 
 
-def _ssm_inputs(p, cfg: ArchConfig, xc):
-    """xc: [B,S,di] post-conv activations → (dA [B,S,di,N], dBx [B,S,di,N],
-    C [B,S,N])."""
+def _ssm_params(p, cfg: ArchConfig, xc):
+    """xc: [B,S,di] post-conv activations → (dt [B,S,di] after softplus,
+    B [B,S,N], C [B,S,N], A [di,N]), float32."""
     di, dt_rank, N, _ = _dims(cfg)
     proj = xc @ p["x_proj"]
     dt_in, Bc, Cc = jnp.split(proj.astype(jnp.float32),
@@ -79,6 +87,13 @@ def _ssm_inputs(p, cfg: ArchConfig, xc):
         Cc = rms_norm(Cc, p["c_norm"], cfg.norm_eps)
     dt = jax.nn.softplus(dt_in @ p["dt_proj"] + p["dt_bias"])   # [B,S,di]
     A = -jnp.exp(p["A_log"].astype(jnp.float32))               # [di,N]
+    return dt, Bc, Cc, A
+
+
+def _ssm_inputs(p, cfg: ArchConfig, xc):
+    """xc: [B,S,di] post-conv activations → (dA [B,S,di,N], dBx [B,S,di,N],
+    C [B,S,N])."""
+    dt, Bc, Cc, A = _ssm_params(p, cfg, xc)
     dA = jnp.exp(dt[..., None] * A)                             # [B,S,di,N]
     dBx = (dt[..., None] * Bc[..., None, :]) * xc.astype(jnp.float32)[..., None]
     return dA, dBx, Cc
@@ -146,22 +161,45 @@ def mamba_scan(p, cfg: ArchConfig, xc, chunk: int):
     return yb.transpose(1, 0, 2, 3).reshape(B, S, di), h_last
 
 
+def mamba_scan_kernel(p, cfg: ArchConfig, xc, interpret: bool = False):
+    """The selective scan by the Pallas kernels: ``xc [B,S,di]`` → ``(y
+    [B,S,di] f32, last state [B,di,N])``.  Δ, B and C go to the kernel
+    unexpanded; its VJP is the backward kernel."""
+    dt, Bc, Cc, A = _ssm_params(p, cfg, xc)
+    return selective_scan(xc, dt, Bc, Cc, A, p["D"], interpret=interpret)
+
+
+def scan_kernel_engages(backend: str, d_inner: int, cost: bool,
+                        channel_shards: int) -> bool:
+    """Whether the scan runs as the Pallas kernels: on a TPU, with the
+    channels a whole number of 128 lanes, outside the cost probe (whose
+    counts need the direct form) and with the channels unsharded (XLA
+    cannot partition a Mosaic call)."""
+    return (backend == "tpu" and d_inner % 128 == 0 and not cost
+            and channel_shards == 1)
+
+
 def mamba_forward(p, cfg: ArchConfig, x, return_cache=False,
                   chunk: int = 64):
-    """x: [B,S,d] → y [B,S,d] (+ cache); the scan is :func:`mamba_scan`,
-    ``chunk`` tokens a step (its [B,chunk,di,N] levels set the scan's
-    memory)."""
+    """x: [B,S,d] → y [B,S,d] (+ cache); the scan is
+    :func:`mamba_scan_kernel` where :func:`scan_kernel_engages`, else
+    :func:`mamba_scan`, ``chunk`` tokens a step (its [B,chunk,di,N] levels
+    set the scan's memory)."""
     B, S, d = x.shape
     xz = shard_last(mm(x, p["in_proj"]))
     xin, z = jnp.split(xz, 2, axis=-1)
     xc, tail = _conv(p, xin, cfg)
     xc = shard_last(xc)
 
-    c = min(chunk, S)
-    if S % c != 0 or cost_mode():  # ragged/test shapes or cost probe
-        c = S
-    y, h_last = mamba_scan({k: p[k] for k in SCAN_KEYS if k in p}, cfg, xc,
-                           chunk=c)
+    sp = {k: p[k] for k in SCAN_KEYS if k in p}
+    if scan_kernel_engages(jax.default_backend(), xc.shape[-1], cost_mode(),
+                           axis_size("model")):
+        y, h_last = mamba_scan_kernel(sp, cfg, xc)
+    else:
+        c = min(chunk, S)
+        if S % c != 0 or cost_mode():  # ragged/test shapes or cost probe
+            c = S
+        y, h_last = mamba_scan(sp, cfg, xc, chunk=c)
     y = shard_last(y)
     y = y.astype(x.dtype) * jax.nn.silu(z)
     out = mm(y, p["out_proj"])

@@ -18,6 +18,12 @@ def _mesh_axis_size(name: str) -> int | None:
     return mesh.shape[name]
 
 
+def axis_size(name: str = "model") -> int:
+    """Devices along mesh axis ``name`` of the ambient mesh; 1 without
+    one."""
+    return _mesh_axis_size(name) or 1
+
+
 def shard_dim(x: jax.Array, dim: int, axis: str = "model") -> jax.Array:
     """Constrain dimension ``dim`` of x over mesh axis ``axis`` (if the
     ambient mesh has it and the dim divides).

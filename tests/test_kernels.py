@@ -1,5 +1,7 @@
 """Per-kernel validation (deliverable c): shape/dtype sweeps, interpret-mode
 Pallas vs the pure-jnp oracle in ref.py, plus hypothesis property tests."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -158,6 +160,31 @@ def test_flash_attention_first_token_attends_self_only():
 # selective_scan
 # ---------------------------------------------------------------------------
 
+def _scan_inputs(B, S, d, N, seed, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    xc = jax.random.normal(ks[0], (B, S, d), dtype)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, d), dtype) - 1)
+    Bm = jax.random.normal(ks[2], (B, S, N), dtype)
+    Cm = jax.random.normal(ks[3], (B, S, N), dtype)
+    A = -jnp.exp(jax.random.normal(ks[4], (d, N)) * 0.3)
+    Dv = jax.random.normal(ks[5], (d,))
+    return xc, dt, Bm, Cm, A, Dv
+
+
+def _scan_sequential(xc, dt, Bm, Cm, A, D):
+    """The recurrence token by token (as ``chipbench/lm_reference.py``
+    runs it) → (y, h_last)."""
+    def one(x, d, b, c):
+        def step(h, inp):
+            d_t, b_t, c_t, x_t = inp
+            h = (jnp.exp(d_t[:, None] * A) * h
+                 + (d_t * x_t)[:, None] * b_t[None])
+            return h, jnp.dot(h, c_t, precision="highest")
+        h, y = jax.lax.scan(step, jnp.zeros(A.shape), (d, b, c, x))
+        return y + D * x, h
+    return jax.vmap(one)(xc, dt, Bm, Cm)
+
+
 @pytest.mark.parametrize("B,S,d,N", [
     (1, 64, 128, 16),
     (2, 256, 512, 16),
@@ -165,37 +192,86 @@ def test_flash_attention_first_token_attends_self_only():
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_selective_scan_sweep(B, S, d, N, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(S + d), 6)
-    xc = jax.random.normal(ks[0], (B, S, d), dtype)
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, d), dtype) - 1)
-    Bm = jax.random.normal(ks[2], (B, S, N), dtype)
-    Cm = jax.random.normal(ks[3], (B, S, N), dtype)
-    A = -jnp.exp(jax.random.normal(ks[4], (d, N)) * 0.3)
-    Dv = jax.random.normal(ks[5], (d,))
-    out = selective_scan(xc, dt, Bm, Cm, A, Dv, bd=128, sc=64, interpret=True)
-    want = ref.selective_scan_ref(xc.astype(jnp.float32),
-                                  dt.astype(jnp.float32),
-                                  Bm.astype(jnp.float32),
-                                  Cm.astype(jnp.float32), A, Dv)
-    tol = dict(atol=1e-4, rtol=1e-3) if dtype == jnp.float32 \
-        else dict(atol=0.15, rtol=0.1)
+    xc, dt, Bm, Cm, A, Dv = _scan_inputs(B, S, d, N, S + d, dtype)
+    out, h_last = selective_scan(xc, dt, Bm, Cm, A, Dv, ts=64,
+                                 interpret=True)
+    want, h_want = ref.selective_scan_ref(xc.astype(jnp.float32),
+                                          dt.astype(jnp.float32),
+                                          Bm.astype(jnp.float32),
+                                          Cm.astype(jnp.float32), A, Dv)
+    # the kernel takes its inputs as float32, so bfloat16 inputs compare
+    # at float32's tolerance once cast
+    tol = dict(atol=1e-4, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), **tol)
+    np.testing.assert_allclose(np.asarray(h_last), np.asarray(h_want), **tol)
 
 
 def test_selective_scan_state_carries_across_blocks():
     """A single long block vs many small sequential blocks must agree —
     proves the VMEM scratch state survives grid steps."""
-    ks = jax.random.split(jax.random.PRNGKey(3), 6)
-    B, S, d, N = 1, 256, 128, 16
-    xc = jax.random.normal(ks[0], (B, S, d), jnp.float32)
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, d)) - 1)
-    Bm = jax.random.normal(ks[2], (B, S, N))
-    Cm = jax.random.normal(ks[3], (B, S, N))
-    A = -jnp.exp(jax.random.normal(ks[4], (d, N)) * 0.3)
-    Dv = jax.random.normal(ks[5], (d,))
-    one = selective_scan(xc, dt, Bm, Cm, A, Dv, bd=128, sc=256,
-                         interpret=True)
-    many = selective_scan(xc, dt, Bm, Cm, A, Dv, bd=128, sc=32,
-                          interpret=True)
-    np.testing.assert_allclose(np.asarray(one), np.asarray(many),
-                               atol=1e-4, rtol=1e-4)
+    xc, dt, Bm, Cm, A, Dv = _scan_inputs(1, 256, 128, 16, 3)
+    one = selective_scan(xc, dt, Bm, Cm, A, Dv, ts=256, interpret=True)
+    many = selective_scan(xc, dt, Bm, Cm, A, Dv, ts=32, interpret=True)
+    for a, b in zip(one, many):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4)
+
+
+# S = 40 is no whole number of 16-step blocks (padded), d = 1,152 nine lane
+# tiles (padded to two channel blocks): both pads are exercised
+@pytest.mark.parametrize("S,d,ts", [(40, 256, 16), (64, 1152, 32)])
+def test_selective_scan_matches_the_token_by_token_recurrence(S, d, ts):
+    args = _scan_inputs(2, S, d, 16, 7 + S)
+    y, h = selective_scan(*args, ts=ts, interpret=True)
+    y_seq, h_seq = _scan_sequential(*args)
+    y_assoc, h_assoc = ref.selective_scan_ref(*args)
+    for got in ((y, h), (y_assoc, h_assoc)):
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(y_seq),
+                                   atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(np.asarray(got[1]), np.asarray(h_seq),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def test_selective_scan_under_vmap_is_the_batched_scan():
+    """The engine vmaps a batch-1 model over its clients: Pallas then adds
+    a grid axis, and the answer is the batch-B call's, bit for bit."""
+    xc, dt, Bm, Cm, A, Dv = _scan_inputs(3, 48, 256, 16, 11)
+    one = functools.partial(selective_scan, ts=16, interpret=True)
+    y, h = one(xc, dt, Bm, Cm, A, Dv)
+    yv, hv = jax.vmap(lambda x, d, b, c: one(x[None], d[None], b[None],
+                                             c[None], A, Dv))(xc, dt, Bm, Cm)
+    assert np.array_equal(np.asarray(yv[:, 0]), np.asarray(y))
+    assert np.array_equal(np.asarray(hv[:, 0]), np.asarray(h))
+
+
+def _scan_loss(scan):
+    def loss(*args):
+        y, h = scan(*args)
+        wy = jnp.cos(jnp.arange(y.size, dtype=jnp.float32)).reshape(y.shape)
+        return jnp.sum(y * wy) + jnp.sum(h * h)
+    return loss
+
+
+@pytest.mark.parametrize("S,ts", [(48, 16), (40, 16)])
+def test_selective_scan_vjp_matches_the_associative_path(S, ts):
+    """All six cotangents (y and h_last both carry one) against jax.grad of
+    the associative form; S = 40 pads the last block."""
+    args = _scan_inputs(2, S, 256, 16, 5)
+    got = jax.grad(_scan_loss(functools.partial(
+        selective_scan, ts=ts, interpret=True)), argnums=range(6))(*args)
+    want = jax.grad(_scan_loss(ref.selective_scan_ref),
+                    argnums=range(6))(*args)
+    for name, g, w in zip(("xc", "dt", "B", "C", "A", "D"), got, want):
+        assert g.shape == w.shape, name
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=2e-6 * scale, rtol=1e-5,
+                                   err_msg=name)
+
+
+def test_selective_scan_backward_repeats_bit_for_bit():
+    args = _scan_inputs(2, 32, 256, 16, 13)
+    grad = jax.jit(jax.grad(_scan_loss(functools.partial(
+        selective_scan, ts=16, interpret=True)), argnums=range(6)))
+    for a, b in zip(grad(*args), grad(*args)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))

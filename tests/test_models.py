@@ -319,6 +319,62 @@ def test_chunked_mamba_matches_single_block():
                                atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("backend,d_inner,cost,shards,kernel", [
+    ("tpu", 5120, False, 1, True),       # the LM cell's scan
+    ("tpu", 512, False, 1, True),
+    ("cpu", 5120, False, 1, False),      # the CPU tests
+    ("gpu", 5120, False, 1, False),
+    ("tpu", 5120 + 64, False, 1, False),  # no whole number of lanes
+    ("tpu", 5120, True, 1, False),       # the cost probe
+    ("tpu", 5120, False, 4, False),      # channels sharded over a mesh
+])
+def test_scan_path_follows_backend_width_cost_and_mesh(backend, d_inner,
+                                                      cost, shards, kernel):
+    from repro.models import mamba as M
+    assert M.scan_kernel_engages(backend, d_inner, cost, shards) is kernel
+
+
+def test_this_machine_runs_the_associative_scan():
+    from repro.models import mamba as M
+    from repro.models.costmode import cost_mode
+    from repro.models.pshard import axis_size
+    assert not M.scan_kernel_engages(jax.default_backend(), 512, cost_mode(),
+                                     axis_size("model"))
+
+
+@pytest.mark.parametrize("name", ["jamba2-3b", "jamba-1.5-large-398b"])
+def test_kernel_scan_path_matches_the_associative_path(name, monkeypatch):
+    """``mamba_forward`` with the kernel path forced (interpret mode) gives
+    the associative path's output, prefill state and parameter gradients
+    (Δ/B/C norms on for jamba2-3b, off for Jamba 1.5)."""
+    import functools
+    from repro.models import mamba as M
+    cfg = tiny(name)
+    key = jax.random.PRNGKey(31)
+    p = M.init_mamba(key, cfg, jnp.float32)
+    x = jax.random.normal(key, (2, 40, cfg.d_model), jnp.float32) * 0.5
+
+    def run(params):
+        y, cache = M.mamba_forward(params, cfg, x, return_cache=True,
+                                   chunk=8)
+        return jnp.sum(y * jnp.sin(y)) + jnp.sum(cache.ssm ** 2), (y, cache)
+
+    (l0, (y0, c0)), g0 = jax.value_and_grad(run, has_aux=True)(p)
+    monkeypatch.setattr(M, "scan_kernel_engages", lambda *a: True)
+    monkeypatch.setattr(M, "mamba_scan_kernel", functools.partial(
+        M.mamba_scan_kernel, interpret=True))
+    (l1, (y1, c1)), g1 = jax.value_and_grad(run, has_aux=True)(p)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y0),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(c1.ssm), np.asarray(c0.ssm),
+                               atol=2e-5, rtol=2e-5)
+    assert np.array_equal(np.asarray(c1.conv), np.asarray(c0.conv))
+    for k in g0:
+        scale = float(jnp.max(jnp.abs(g0[k]))) + 1e-12
+        np.testing.assert_allclose(np.asarray(g1[k]), np.asarray(g0[k]),
+                                   atol=1e-5 * scale, rtol=1e-4, err_msg=k)
+
+
 def test_chunked_mlstm_matches_single_block():
     from repro.models import xlstm as X
     cfg = tiny("xlstm-125m", d_model=64, n_heads=2)
