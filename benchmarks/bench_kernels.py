@@ -9,8 +9,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.fl.state import masked_aggregate
 from repro.kernels import ref
-from repro.kernels.fl_aggregate import BLOCK_R, LANE, fl_aggregate
+from repro.kernels.fl_aggregate import BLOCK_R, LANE
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.selective_scan import selective_scan
 from repro.launch.cache import enable_compile_cache
@@ -36,9 +37,9 @@ def main() -> dict:
     g = jax.random.normal(key, (M,), jnp.float32)
     d = jax.random.normal(key, (K, M), jnp.float32)
     m = (jax.random.uniform(key, (K,)) < 0.5).astype(jnp.float32)
-    t_ref = _time(lambda: ref.fl_aggregate_ref(g, d, m))
-    err = float(jnp.abs(fl_aggregate(g, d, m, interpret=True)
-                        - ref.fl_aggregate_ref(g, d, m)).max())
+    t_ref = _time(lambda: ref.fl_aggregate_ref(g, d, m, K))
+    err = float(jnp.abs(masked_aggregate(g, d, m, K, use_pallas="interpret")
+                        - ref.fl_aggregate_ref(g, d, m, K)).max())
     hbm_naive = (K * M * 4) * 2 + M * 8          # unfused: read δ, write temp, rw global
     hbm_fused = K * M * 4 + M * 8                # fused single pass
     out["fl_aggregate"] = {"ref_us": t_ref * 1e6, "maxerr": err,

@@ -289,16 +289,27 @@ def phase_kernel() -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.kernels import ops, ref
+    from repro.fl.faults import GuardConfig
+    from repro.fl.state import masked_aggregate, round_weights
+    from repro.kernels import ref
 
+    def quarantined(g, d, w):
+        w, denom, d = round_weights(w, 1, jnp.zeros(w.shape, jnp.int32),
+                                    None, None, GuardConfig(quarantine=True),
+                                    d)
+        return masked_aggregate(g, d, w, denom, use_pallas=True)
+
+    # static K (the dense engine), traced K (the sparse path and the
+    # server), and weights that hold their own scaling (guards, schemes)
     variants = {
-        "plain": (lambda g, d, w: ops.fl_aggregate(g, d, w, use_pallas=True),
-                  ref.fl_aggregate_ref),
-        "subset": (lambda g, d, w: ops.fl_aggregate_subset(
-            g, d, w, 1000, use_pallas=True),
-            lambda g, d, w: ref.fl_aggregate_subset_ref(g, d, w, 1000)),
-        "guarded": (lambda g, d, w: ops.fl_aggregate_guarded(
-            g, d, w, use_pallas=True), ref.fl_aggregate_guarded_ref),
+        "plain": (lambda g, d, w: masked_aggregate(g, d, w, d.shape[0],
+                                                   use_pallas=True),
+                  lambda g, d, w: ref.fl_aggregate_ref(g, d, w, d.shape[0])),
+        "subset": (lambda g, d, w: masked_aggregate(
+            g, d, w, jnp.int32(1000), use_pallas=True),
+            lambda g, d, w: ref.fl_aggregate_ref(g, d, w, 1000)),
+        "guarded": (quarantined, lambda g, d, w: ref.fl_aggregate_ref(
+            g, jnp.where(jnp.isfinite(d), d, 0.0), w, 1)),
     }
     compile_s = run_s = err = 0.0
     n = 0

@@ -17,11 +17,9 @@ from .sparse import (ParticipationTrace, build_participation_program,
                      resolve_participation, train_trace_count)
 from .state import (AggParams, AggregatorConfig, FLState,
                     broadcast_to_participants, finite_rows, guard_weights,
-                    guarded_aggregate, guarded_subset_aggregate,
                     init_fl_state, masked_aggregate, pseudo_gradients,
-                    scheme_aggregate, scheme_subset_aggregate, scheme_weights,
-                    staleness_scale, subset_aggregate, update_norms,
-                    weighted_aggregate)
+                    round_weights, scheme_weights, staleness_scale,
+                    update_norms)
 
 __all__ = ["SimConfig", "SimResult", "run_simulation",
            "run_simulation_legacy", "run_simulation_scan", "build_scan_sim",
@@ -29,7 +27,7 @@ __all__ = ["SimConfig", "SimResult", "run_simulation",
            "run_seed_matrix", "run_scenario_matrix", "stack_round_batches",
            "grant_forced_bandwidth", "MatrixResult", "RoundTrace", "FLState",
            "init_fl_state", "init_carry", "masked_aggregate",
-           "pseudo_gradients", "subset_aggregate",
+           "round_weights", "pseudo_gradients",
            "broadcast_to_participants", "make_sparse_runner",
            "resolve_participation", "build_participation_program",
            "build_sparse_train_program", "ParticipationTrace",
@@ -39,10 +37,9 @@ __all__ = ["SimConfig", "SimResult", "run_simulation",
            "GuardConfig", "FaultMatrixResult", "apply_faults",
            "corrupt_deltas", "fault_key", "init_fault_state", "scale_params",
            "run_fault_matrix", "finite_rows", "update_norms",
-           "guard_weights", "guarded_aggregate", "guarded_subset_aggregate",
+           "guard_weights",
            "run_resumable", "segment_bounds", "completed_segments",
            # scheme matrix (docs/schemes.md)
            "AggParams", "AggregatorConfig", "SchemeMatrixResult",
            "SchemeSpec", "default_scheme_panel", "run_scheme_matrix",
-           "scheme_aggregate", "scheme_subset_aggregate", "scheme_weights",
-           "staleness_scale", "weighted_aggregate"]
+           "scheme_weights", "staleness_scale"]

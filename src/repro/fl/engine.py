@@ -52,8 +52,8 @@ from ..optim import Optimizer, sgd
 from .faults import (FaultConfig, FaultState, GuardConfig, apply_faults,
                      corrupt_deltas, init_fault_state)
 from .state import (AggregatorConfig, FLState, broadcast_to_participants,
-                    guarded_aggregate, init_fl_state, masked_aggregate,
-                    pseudo_gradients, scheme_aggregate)
+                    init_fl_state, masked_aggregate, pseudo_gradients,
+                    round_weights)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -428,31 +428,19 @@ def _make_round_step(vtrain: Callable, loss_fn: Callable, acc_fn: Callable,
         deltas = pseudo_gradients(state)
         if faults is not None:
             deltas = corrupt_deltas(deltas, corrupt, fp, faults)
-        if agg is not None:
-            # pluggable scheme path (guards fold in): weights come from the
-            # staleness ledger and the policy's *nominal* probs (pre-boost —
-            # the csmaafl importance weight debiases the policy, not the
-            # aging heuristic layered on top of it)
-            staleness = state.round - state.last_tx
-            new_global = scheme_aggregate(
-                state.global_params, deltas, delivered, K, staleness, probs,
-                agg.params() if ap is None else ap, guards=guards,
-                use_pallas=use_pallas)
-        elif guards is not None and guards.active:
-            staleness = state.round - state.last_tx
-            new_global = guarded_aggregate(state.global_params, deltas,
-                                           delivered, K, staleness, guards,
-                                           use_pallas=use_pallas)
-        else:
-            new_global = masked_aggregate(state.global_params, deltas,
-                                          delivered, K,
-                                          use_pallas=use_pallas)
+        # a scheme's weights come from the staleness ledger and the
+        # policy's *nominal* probs (pre-boost — the csmaafl importance
+        # weight debiases the policy, not the aging heuristic on top of it)
+        staleness = state.round - state.last_tx
+        ap_eff = None if agg is None else (agg.params() if ap is None else ap)
+        w_agg, denom, d_agg = round_weights(delivered, K, staleness, probs,
+                                            ap_eff, guards, deltas)
+        new_global = masked_aggregate(state.global_params, d_agg, w_agg,
+                                      denom, use_pallas=use_pallas)
         if tapped:
-            ap_eff = ((agg.params() if ap is None else ap)
-                      if agg is not None else None)
             mstate = metrics_round_update(
                 mstate, cfg.metrics, mask=mask, forced=forced, e_base=e_base,
-                e_round=e_round, staleness=state.round - state.last_tx,
+                e_round=e_round, staleness=staleness,
                 delivered=delivered, deltas=deltas, probs=probs,
                 num_clients=K, guards=guards, agg_params=ap_eff)
         state = broadcast_to_participants(state, new_global, delivered)

@@ -41,7 +41,8 @@ the engine's program byte-for-byte unchanged (the existing dense/sparse/
 legacy bit-parity tests keep passing untouched).
 
 Server-side defenses are configured here too (:class:`GuardConfig`) and
-implemented mask-based in :func:`repro.fl.state.guarded_aggregate`:
+implemented mask-based in :func:`repro.fl.state.guard_weights`, which
+:func:`repro.fl.state.round_weights` applies to every round:
 non-finite quarantine (reject-and-reweight instead of poisoning the global
 model), update-norm clipping, and staleness-gated down-weighting.
 

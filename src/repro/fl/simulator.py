@@ -40,8 +40,8 @@ from .engine import (SimConfig, SimResult, apply_round_decision,
 from .faults import (FaultConfig, GuardConfig, apply_faults, corrupt_deltas,
                      init_fault_state)
 from .state import (AggregatorConfig, FLState, broadcast_to_participants,
-                    guarded_aggregate, init_fl_state, masked_aggregate,
-                    pseudo_gradients, scheme_aggregate)
+                    init_fl_state, masked_aggregate, pseudo_gradients,
+                    round_weights)
 
 __all__ = ["SimConfig", "SimResult", "run_simulation",
            "run_simulation_legacy", "make_round_fn"]
@@ -93,27 +93,17 @@ def make_round_fn(loss_fn: Callable, opt: Optimizer, local_iters: int,
         deltas = pseudo_gradients(state)
         if faults is not None and corrupt is not None:
             deltas = corrupt_deltas(deltas, corrupt, fparams, faults)
-        if aggregator is not None:
-            staleness = state.round - state.last_tx
-            p = (jnp.zeros((num_clients,), jnp.float32) if probs is None
-                 else probs)
-            new_global = scheme_aggregate(state.global_params, deltas,
-                                          landed, num_clients, staleness,
-                                          p, aparams, guards=guards)
-        elif guards is not None and guards.active:
-            staleness = state.round - state.last_tx
-            new_global = guarded_aggregate(state.global_params, deltas,
-                                           landed, num_clients, staleness,
-                                           guards)
-        else:
-            new_global = masked_aggregate(state.global_params, deltas,
-                                          landed, num_clients)
+        staleness = state.round - state.last_tx
+        p = (jnp.zeros((num_clients,), jnp.float32) if probs is None
+             else probs)
+        w_agg, denom, d_agg = round_weights(landed, num_clients, staleness, p,
+                                            aparams, guards, deltas)
+        new_global = masked_aggregate(state.global_params, d_agg, w_agg,
+                                      denom)
         if ttap:
-            p = (jnp.zeros((num_clients,), jnp.float32) if probs is None
-                 else probs)
             ms = update_train_taps(
                 mstate, metrics, deltas=deltas, delivered=landed,
-                staleness=state.round - state.last_tx, probs=p,
+                staleness=staleness, probs=p,
                 num_clients=num_clients, guards=guards, agg_params=aparams)
             return broadcast_to_participants(state, new_global, landed), ms
         return broadcast_to_participants(state, new_global, landed)

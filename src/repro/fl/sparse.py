@@ -25,8 +25,8 @@ transition so the expensive work scales with the *transmitting set*:
   The carry is a global-model *history* ``[T+1, D]`` (slot s = the model
   broadcast after round s-1); each round gathers its participants' anchors
   ``hist[slot_p]``, runs local SGD over the ``[P, ...]`` bucket, and applies
-  the participant-subset eq.-3 update (:func:`repro.fl.state.subset_aggregate`,
-  Pallas-fused on TPU) with the population size as a *traced* scalar.  One
+  the eq.-3 update (:func:`repro.fl.state.masked_aggregate` over the bucket
+  rows, Pallas-fused on TPU) with the population size as a *traced* scalar.  One
   compile serves every K sharing a bucket — the fix for the engine's
   one-compile-per-K limitation (``TRAIN_TRACE_COUNT`` counts traces; the
   K-sweep test pins it to one).
@@ -64,8 +64,7 @@ from ..obs.taps import (MetricsState, init_metrics, merge_metrics,
 from ..obs.telemetry import emit_run_manifest, get_telemetry
 from ..optim import Optimizer, sgd
 from .faults import apply_faults, corrupt_deltas, init_fault_state
-from .state import (FLState, guarded_subset_aggregate,
-                    scheme_subset_aggregate, subset_aggregate)
+from .state import FLState, masked_aggregate, round_weights
 
 #: number of times the participant-shaped training program has been traced.
 #: Shapes depend only on (bucket, T, model), so a K-sweep sharing a bucket
@@ -365,12 +364,12 @@ def build_sparse_train_program(loss_fn: Callable, acc_fn: Callable,
     The trailing optional operands are the fault pipeline's per-participant
     outcomes from phase A: lost uploads aggregate with weight 0, corrupt
     flags drive :func:`~repro.fl.faults.corrupt_deltas`, and staleness feeds
-    the defensive :func:`~repro.fl.state.guarded_subset_aggregate` when
+    the guard weights of :func:`~repro.fl.state.round_weights` when
     ``cfg.guards`` is active.  Omitted (the faults-off call) they default to
     ``delivered = valid`` / no corruption — the pre-robustness program.
 
-    With ``cfg.aggregator`` set the update swaps to the pluggable scheme
-    aggregation (:func:`~repro.fl.state.scheme_subset_aggregate`); phase A's
+    With ``cfg.aggregator`` set the update takes the pluggable scheme's
+    weights (:func:`~repro.fl.state.round_weights`); phase A's
     nominal-prob lane rides in as ``probs_all`` and ``agg_params`` can be a
     traced :class:`~repro.fl.state.AggParams` (vmapped scheme panels).
     """
@@ -426,15 +425,9 @@ def build_sparse_train_program(loss_fn: Callable, acc_fn: Callable,
                                             anchors)
             if faults is not None:
                 deltas = corrupt_deltas(deltas, corr, fparams, faults)
-            if agg is not None:
-                g_new = scheme_subset_aggregate(g_t, deltas, deliv,
-                                                num_clients, stale, prob,
-                                                ap, guards=guards)
-            elif guards is not None and guards.active:
-                g_new = guarded_subset_aggregate(g_t, deltas, deliv,
-                                                 num_clients, stale, guards)
-            else:
-                g_new = subset_aggregate(g_t, deltas, deliv, num_clients)
+            w_agg, denom, d_agg = round_weights(deliv, num_clients, stale,
+                                                prob, ap, guards, deltas)
+            g_new = masked_aggregate(g_t, d_agg, w_agg, denom)
             hist = jax.tree_util.tree_map(
                 lambda h, g: h.at[t + 1].set(g), hist, g_new)
             do_eval = jnp.logical_or(t % cfg.eval_every == 0, t == T - 1)

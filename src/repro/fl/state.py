@@ -14,6 +14,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels.fl_aggregate import fl_aggregate
+
 
 class FLState(NamedTuple):
     global_params: Any   # pytree, the server's x_t
@@ -46,27 +48,38 @@ def pseudo_gradients(state: FLState) -> Any:
 
 
 def masked_aggregate(global_params: Any, deltas: Any, mask: jax.Array,
-                     num_clients: int,
-                     use_pallas: bool | str | None = None) -> Any:
-    """Eq. (3): x ← x + (1/K) Σ_{k∈C_t} δ_k.
+                     num_clients, use_pallas: bool | str | None = None) -> Any:
+    """Eq. (3), the one aggregation update: x ← x + (Σ_r mask_r·δ_r) / K.
 
-    ``use_pallas=None`` auto-selects by backend: on TPU every leaf routes
-    through the fused ``kernels.fl_aggregate`` kernel (the op sits on the hot
-    path of the scan engine, one HBM pass per tile); elsewhere the jnp path is
-    both the oracle and the fastest option.  ``True``/``False`` force a path;
-    ``"interpret"`` runs the kernel body in the Pallas interpreter (the CPU
-    parity tests) — see :mod:`repro.kernels.ops`.
+    ``mask`` is any per-row weight: the {0, 1} delivery mask of the paper's
+    update, or the weights :func:`round_weights` builds for guards and
+    schemes.  ``num_clients`` is the divisor — the population K, or 1 when
+    the weights already hold the scaling.  The row axis may be the
+    population (dense engine) or a padded participant bucket (sparse phase
+    B, the server's micro-batch).
+
+    ``use_pallas=None`` picks by backend: on a TPU every leaf runs through
+    the fused ``kernels.fl_aggregate`` kernel, elsewhere the jnp expression
+    below.  ``True``/``False`` force a path (the client-sharded engine
+    forces jnp: XLA cannot partition a Mosaic kernel); ``"interpret"`` runs
+    the kernel body in the Pallas interpreter (the CPU parity tests).  A
+    Python-int divisor reaches the kernel as its static ``denom``; a traced
+    one (the sparse path and the server pass the population as data, so one
+    program serves every K) is folded into the weights.
     """
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
+    traced = not isinstance(num_clients, int)
+    if traced:
+        num_clients = jnp.asarray(num_clients, jnp.float32)
     if use_pallas:
-        from ..kernels import ops
+        w = mask.astype(jnp.float32)
+        w, denom = (w / num_clients, 1) if traced else (w, num_clients)
 
         def agg_k(g, d):
-            out = ops.fl_aggregate(g.reshape(-1),
-                                   d.reshape(d.shape[0], -1),
-                                   mask.astype(jnp.float32),
-                                   use_pallas=use_pallas)
+            out = fl_aggregate(g.reshape(-1), d.reshape(d.shape[0], -1), w,
+                               interpret=use_pallas == "interpret",
+                               denom=denom)
             return out.reshape(g.shape).astype(g.dtype)
 
         return jax.tree_util.tree_map(agg_k, global_params, deltas)
@@ -76,40 +89,6 @@ def masked_aggregate(global_params: Any, deltas: Any, mask: jax.Array,
         return g + jnp.sum(d * m, axis=0) / num_clients
 
     return jax.tree_util.tree_map(agg, global_params, deltas)
-
-
-def subset_aggregate(global_params: Any, deltas_p: Any, valid: jax.Array,
-                     num_clients, use_pallas: bool | str | None = None) -> Any:
-    """Participant-subset eq. (3): x ← x + (1/K) Σ_p valid_p · δ_p.
-
-    ``deltas_p`` carries a leading *participant bucket* axis P (the gathered
-    transmitting set, padded), not the population axis K; ``valid`` masks the
-    padding lanes and ``num_clients`` is the population size the paper's
-    1/K averaging divides by — it may be a **traced** scalar, which is what
-    lets one compiled sparse round step serve every population sharing a
-    bucket.  Backend dispatch matches :func:`masked_aggregate`: the fused
-    Pallas kernel on TPU (subset form — see ``kernels.ops.fl_aggregate_subset``),
-    the jnp oracle elsewhere.
-    """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    kf = jnp.asarray(num_clients, jnp.float32)
-    if use_pallas:
-        from ..kernels import ops
-
-        def agg_k(g, d):
-            out = ops.fl_aggregate_subset(
-                g.reshape(-1), d.reshape(d.shape[0], -1),
-                valid.astype(jnp.float32), kf, use_pallas=use_pallas)
-            return out.reshape(g.shape).astype(g.dtype)
-
-        return jax.tree_util.tree_map(agg_k, global_params, deltas_p)
-
-    def agg(g, d):
-        m = valid.astype(d.dtype).reshape((-1,) + (1,) * (d.ndim - 1))
-        return g + jnp.sum(d * m, axis=0) / kf
-
-    return jax.tree_util.tree_map(agg, global_params, deltas_p)
 
 
 def finite_rows(deltas: Any) -> jax.Array:
@@ -177,68 +156,6 @@ def guard_weights(deltas: Any, staleness: jax.Array, guards) -> tuple:
     if guards.staleness_cap is not None:
         w = w * (staleness <= guards.staleness_cap).astype(jnp.float32)
     return w, out
-
-
-def guarded_aggregate(global_params: Any, deltas: Any, mask: jax.Array,
-                      num_clients, staleness: jax.Array, guards,
-                      use_pallas: bool | str | None = None) -> Any:
-    """Eq. (3) with server-side defenses: x ← x + (1/K) Σ_k m_k·g_k·δ_k.
-
-    ``guards=None`` (or an all-off config) routes straight to
-    :func:`masked_aggregate` — bit-identical to the undefended path.  On TPU
-    the quarantine runs *inside* the fused kernel
-    (``kernels.ops.fl_aggregate_guarded``: non-finite elements are zeroed in
-    VMEM, no sanitized [K, D] copy is ever materialized in HBM).
-    """
-    if guards is None or not guards.active:
-        return masked_aggregate(global_params, deltas, mask, num_clients,
-                                use_pallas=use_pallas)
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    w, safe = guard_weights(deltas, staleness, guards)
-    m = mask.astype(jnp.float32) * w
-    if use_pallas:
-        from ..kernels import ops
-        inv = 1.0 / jnp.asarray(num_clients, jnp.float32)
-
-        def agg_k(g, d):
-            out = ops.fl_aggregate_guarded(g.reshape(-1),
-                                           d.reshape(d.shape[0], -1),
-                                           m * inv, use_pallas=use_pallas)
-            return out.reshape(g.shape).astype(g.dtype)
-
-        # the kernel zeroes non-finite elements itself — pass raw deltas
-        return jax.tree_util.tree_map(agg_k, global_params, deltas)
-    return masked_aggregate(global_params, safe, m, num_clients,
-                            use_pallas=False)
-
-
-def guarded_subset_aggregate(global_params: Any, deltas_p: Any,
-                             valid: jax.Array, num_clients,
-                             staleness_p: jax.Array, guards,
-                             use_pallas: bool | str | None = None) -> Any:
-    """Participant-subset form of :func:`guarded_aggregate` (sparse path):
-    rows are the padded transmitting bucket, ``num_clients`` may be traced."""
-    if guards is None or not guards.active:
-        return subset_aggregate(global_params, deltas_p, valid, num_clients,
-                                use_pallas=use_pallas)
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    w, safe = guard_weights(deltas_p, staleness_p, guards)
-    v = valid.astype(jnp.float32) * w
-    if use_pallas:
-        from ..kernels import ops
-        inv = 1.0 / jnp.asarray(num_clients, jnp.float32)
-
-        def agg_k(g, d):
-            out = ops.fl_aggregate_guarded(g.reshape(-1),
-                                           d.reshape(d.shape[0], -1),
-                                           v * inv, use_pallas=use_pallas)
-            return out.reshape(g.shape).astype(g.dtype)
-
-        return jax.tree_util.tree_map(agg_k, global_params, deltas_p)
-    return subset_aggregate(global_params, safe, v, num_clients,
-                            use_pallas=False)
 
 
 # ---------------------------------------------------------------------------
@@ -390,72 +307,36 @@ def scheme_weights(mask: jax.Array, staleness: jax.Array, probs: jax.Array,
     return ap.kind_paper * a_paper + (1.0 - ap.kind_paper) * ap.mix * norm
 
 
-def weighted_aggregate(global_params: Any, deltas: Any, weights: jax.Array,
-                       use_pallas: bool | str | None = None) -> Any:
-    """Generic weighted update: x ← x + Σ_r a_r·δ_r.
+def round_weights(mask: jax.Array, num_clients, staleness: jax.Array,
+                  probs: jax.Array, agg, guards, deltas: Any) -> tuple:
+    """The rule for which eq.-3 update a round applies, as the arguments
+    of :func:`masked_aggregate`: ``(weights, denom, deltas)``.
 
-    The row axis may be the population (dense engine) or the participant
-    bucket (sparse phase B) — the weights carry the masking, the 1/K (or
-    normalization), and any guard scaling.  On TPU this is the fused
-    ``kernels.ops.fl_aggregate_guarded`` kernel (it computes exactly this
-    weighted sum, zeroing non-finite elements in VMEM); elsewhere the jnp
-    path is the oracle.
+    Scheme > guards > plain:
+
+    * plain (no scheme, guards off or ``None``): ``(mask, K, deltas)`` —
+      the paper's update, its arguments untouched;
+    * guards active: ``(mask·g_w, K, deltas')`` with the guard weights and
+      the quarantine-zeroed rows of :func:`guard_weights`;
+    * a scheme (``agg`` an :class:`AggregatorConfig` or its traced
+      :class:`AggParams`): ``(scheme_weights(...), 1, deltas')`` — the
+      weights hold the scaling, and guard weights, when active, fold into
+      the mask first, so defenses compose with every scheme.
+
+    Rows are the population or the participant bucket; ``num_clients`` may
+    be traced; ``probs`` feeds only the csmaafl importance weight.
     """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
-        from ..kernels import ops
-
-        def agg_k(g, d):
-            out = ops.fl_aggregate_guarded(g.reshape(-1),
-                                           d.reshape(d.shape[0], -1),
-                                           weights.astype(jnp.float32),
-                                           use_pallas=use_pallas)
-            return out.reshape(g.shape).astype(g.dtype)
-
-        return jax.tree_util.tree_map(agg_k, global_params, deltas)
-
-    def agg(g, d):
-        a = weights.astype(d.dtype).reshape((-1,) + (1,) * (d.ndim - 1))
-        return g + jnp.sum(d * a, axis=0)
-
-    return jax.tree_util.tree_map(agg, global_params, deltas)
-
-
-def scheme_aggregate(global_params: Any, deltas: Any, mask: jax.Array,
-                     num_clients, staleness: jax.Array, probs: jax.Array,
-                     agg, guards=None,
-                     use_pallas: bool | str | None = None) -> Any:
-    """Population-row aggregation under a pluggable scheme (+ optional
-    guards).
-
-    ``agg`` is an :class:`AggregatorConfig` or its traced :class:`AggParams`;
-    guard weights (quarantine / norm clip / staleness defenses) fold into the
-    mask before the scheme weights are computed, so defenses compose with
-    every aggregation scheme exactly as they do with the paper's eq. 3.
-    """
-    ap = agg.params() if isinstance(agg, AggregatorConfig) else agg
+    guarded = guards is not None and guards.active
+    if agg is None and not guarded:
+        return mask, num_clients, deltas
     m = mask.astype(jnp.float32)
-    safe = deltas
-    if guards is not None and guards.active:
-        gw, safe = guard_weights(deltas, staleness, guards)
+    if guarded:
+        gw, deltas = guard_weights(deltas, staleness, guards)
         m = m * gw
-    a = scheme_weights(m, staleness, probs, ap, num_clients)
-    return weighted_aggregate(global_params, safe, a, use_pallas=use_pallas)
-
-
-def scheme_subset_aggregate(global_params: Any, deltas_p: Any,
-                            valid: jax.Array, num_clients,
-                            staleness_p: jax.Array, probs_p: jax.Array,
-                            agg, guards=None,
-                            use_pallas: bool | str | None = None) -> Any:
-    """Participant-subset form of :func:`scheme_aggregate` (sparse phase B):
-    rows are the padded transmitting bucket and ``num_clients`` may be a
-    traced scalar, so one compiled bucket program serves every population
-    *and* every aggregation scheme (AggParams ride a vmap axis)."""
-    return scheme_aggregate(global_params, deltas_p, valid, num_clients,
-                            staleness_p, probs_p, agg, guards=guards,
-                            use_pallas=use_pallas)
+    if agg is None:
+        return m, num_clients, deltas
+    ap = agg.params() if isinstance(agg, AggregatorConfig) else agg
+    return scheme_weights(m, staleness, probs, ap, num_clients), 1, deltas
 
 
 def broadcast_to_participants(state: FLState, new_global: Any,

@@ -34,7 +34,7 @@ ROW_TILE = 64
 
 
 def _kernel(mask_ref, global_ref, deltas_ref, out_ref, acc_ref, *,
-            inv_k: float, guard: bool):
+            inv_k: float):
     r = pl.program_id(1)
 
     @pl.when(r == 0)
@@ -42,12 +42,6 @@ def _kernel(mask_ref, global_ref, deltas_ref, out_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     d = deltas_ref[...].astype(jnp.float32)          # [RT, TILE_M]
-    if guard:
-        # non-finite quarantine, fused: a rejected row arrives with mask 0,
-        # but 0 · NaN = NaN — zero the poison in VMEM so the zero weight
-        # actually rejects it.  One extra VPU pass over data already
-        # resident; no sanitized [K, M] copy ever exists in HBM.
-        d = jnp.where(jnp.isfinite(d), d, 0.0)
     m = mask_ref[...].astype(jnp.float32)            # [RT, 1]
     acc_ref[...] += jnp.sum(d * m, axis=0, keepdims=True)
 
@@ -57,24 +51,23 @@ def _kernel(mask_ref, global_ref, deltas_ref, out_ref, acc_ref, *,
                         + acc_ref[...] * inv_k).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "denom", "guard"))
+@functools.partial(jax.jit, static_argnames=("interpret", "denom"))
 def fl_aggregate(global_p: jax.Array, deltas: jax.Array, mask: jax.Array,
                  interpret: bool = False,
-                 denom: int | None = None,
-                 guard: bool = False) -> jax.Array:
+                 denom: int | None = None) -> jax.Array:
     """global_p: [M]; deltas: [R, M]; mask: [R] → updated global [M].
 
     ``R`` is the *row* count of the delta block — the full population K in
     the dense path, or a padded participant bucket P in the sparse path.
-    ``denom`` is the eq.-3 averaging denominator (the population size K);
-    it defaults to ``R``, which is only correct when the rows ARE the whole
-    population.  The sparse path passes ``deltas: [P, M]`` for the gathered
-    transmitting set with ``mask`` = its validity lanes and ``denom=K``, so
-    one compiled kernel shape serves every population size sharing a bucket.
-
-    ``guard=True`` zeroes non-finite delta elements inside the kernel
-    (defensive aggregation: a quarantined row carries mask 0, and in-VMEM
-    sanitization keeps its NaN/Inf from poisoning the reduction).
+    ``denom`` is the static eq.-3 divisor, the population K of the dense
+    path; it defaults to ``R``, which is only correct when the rows ARE the
+    whole population.  Where K is traced (the sparse path's gathered
+    transmitting set ``[P, M]``, the server's micro-batch) the caller folds
+    1/K into ``mask`` and passes ``denom=1``, so one compiled kernel shape
+    serves every population size sharing a bucket; guard and scheme weights,
+    which hold their own scaling, take ``denom=1`` too.  Non-finite deltas
+    are not sanitised here: quarantine zeroes a rejected row before it
+    arrives (:func:`repro.fl.state.guard_weights`).
 
     ``interpret=True`` evaluates the kernel body with the Pallas
     interpreter (how the CPU tests run it); the default compiles for TPU.
@@ -94,7 +87,7 @@ def fl_aggregate(global_p: jax.Array, deltas: jax.Array, mask: jax.Array,
     grid = (Mp // tile, Rp // rt)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, inv_k=inv_k, guard=guard),
+        functools.partial(_kernel, inv_k=inv_k),
         grid=grid,
         in_specs=[
             pl.BlockSpec((rt, 1), lambda i, r: (r, 0)),

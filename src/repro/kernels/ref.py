@@ -6,43 +6,17 @@ import jax.numpy as jnp
 
 
 def fl_aggregate_ref(global_p: jax.Array, deltas: jax.Array,
-                     mask: jax.Array) -> jax.Array:
-    """Eq. (3): out = global + (1/K) Σ_k mask_k · δ_k.
+                     weights: jax.Array, denom=None) -> jax.Array:
+    """Eq. (3): out = global + (Σ_r w_r · δ_r) / denom.
 
-    global_p: [M]; deltas: [K, M]; mask: [K].
+    global_p: [M]; deltas: [R, M]; weights: [R].  ``denom`` defaults to R
+    (the rows are the whole population); it may be a traced population
+    size, or 1 when the weights already hold the scaling.
     """
-    K = deltas.shape[0]
+    denom = deltas.shape[0] if denom is None else denom
     agg = jnp.sum(deltas.astype(jnp.float32)
-                  * mask.astype(jnp.float32)[:, None], axis=0) / K
-    return (global_p.astype(jnp.float32) + agg).astype(global_p.dtype)
-
-
-def fl_aggregate_subset_ref(global_p: jax.Array, deltas: jax.Array,
-                            valid: jax.Array, num_clients) -> jax.Array:
-    """Participant-subset eq. (3): out = global + (1/K) Σ_p valid_p · δ_p.
-
-    global_p: [M]; deltas: [P, M] (gathered transmitting set, padded);
-    valid: [P] lanes; ``num_clients`` is the population K — may be a traced
-    scalar, so one compiled program serves every K sharing a bucket.
-    """
-    agg = jnp.sum(deltas.astype(jnp.float32)
-                  * valid.astype(jnp.float32)[:, None], axis=0)
-    agg = agg / jnp.asarray(num_clients, jnp.float32)
-    return (global_p.astype(jnp.float32) + agg).astype(global_p.dtype)
-
-
-def fl_aggregate_guarded_ref(global_p: jax.Array, deltas: jax.Array,
-                             weights: jax.Array) -> jax.Array:
-    """Defensively-weighted eq. (3) oracle: out = global + Σ_r w_r·δ'_r with
-    δ' = δ where finite else 0.
-
-    global_p: [M]; deltas: [R, M]; weights: [R] — the caller folds the
-    participation mask, guard weights and the 1/K denominator into
-    ``weights`` (matching the ``denom=1`` kernel contract).
-    """
-    d = deltas.astype(jnp.float32)
-    d = jnp.where(jnp.isfinite(d), d, 0.0)
-    agg = jnp.sum(d * weights.astype(jnp.float32)[:, None], axis=0)
+                  * weights.astype(jnp.float32)[:, None], axis=0)
+    agg = agg / jnp.asarray(denom, jnp.float32)
     return (global_p.astype(jnp.float32) + agg).astype(global_p.dtype)
 
 

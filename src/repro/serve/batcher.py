@@ -6,12 +6,11 @@ Two pieces:
   delta pytrees to a pow2 *bucket* (:func:`pick_bucket`, mirroring the
   sparse engine's ``participant_bucket`` discipline: a handful of bucket
   shapes ⇒ a handful of compiles, whatever the traffic level) and drives
-  the **same** participant-subset aggregation family as the scan engine's
-  phase B — ``scheme_subset_aggregate`` / ``guarded_subset_aggregate`` /
-  ``subset_aggregate``, in the same precedence order, with the population
-  size as the 1/K divisor.  Replay parity depends on this: an offline
-  re-run through ``build_sparse_train_program`` hits the identical
-  aggregation code on identically-padded lanes.
+  the **same** aggregation as the scan engine's phase B —
+  ``round_weights`` then ``masked_aggregate`` over the bucket rows, with
+  the population size as the traced 1/K divisor.  Replay parity depends
+  on this: an offline re-run through ``build_sparse_train_program`` hits
+  the identical aggregation code on identically-padded lanes.
 * :class:`MicroBatcher` — the host side.  A daemon thread parked on the
   server's condition variable; it flushes when a full ``max_batch`` is
   pending or the oldest pending update has waited ``flush_interval_s``
@@ -27,8 +26,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..fl.state import (guarded_subset_aggregate, scheme_subset_aggregate,
-                        subset_aggregate)
+from ..fl.state import masked_aggregate, round_weights
 
 
 def pick_bucket(n: int, min_bucket: int, max_batch: int) -> int:
@@ -59,15 +57,9 @@ def build_apply_fn(guards, aggregator, num_clients: int):
     else:
         @jax.jit
         def _agg(g, deltas_p, valid, stale_p, probs_p):
-            # precedence mirrors fl/sparse.build_sparse_train_program exactly
-            if aggregator is not None:
-                return scheme_subset_aggregate(g, deltas_p, valid, kf,
-                                               stale_p, probs_p, ap,
-                                               guards=guards)
-            if guards is not None and guards.active:
-                return guarded_subset_aggregate(g, deltas_p, valid, kf,
-                                                stale_p, guards)
-            return subset_aggregate(g, deltas_p, valid, kf)
+            w, denom, d = round_weights(valid, kf, stale_p, probs_p, ap,
+                                        guards, deltas_p)
+            return masked_aggregate(g, d, w, denom)
         _AGG_CACHE[cache_key] = _agg
 
     def apply(g: Any, deltas: list, bucket: int, stale: jax.Array,

@@ -10,7 +10,7 @@ dedup** (one in-flight update per client — a client re-submitting before
 its previous update aggregated is told to wait).  A background
 :class:`~repro.serve.batcher.MicroBatcher` coalesces pending updates into
 pow2-bucketed micro-batches and drives the same jitted
-``subset_aggregate`` family as the scan engine.
+``round_weights`` + ``masked_aggregate`` update as the scan engine.
 
 The server also plays the paper's control plane: after every applied
 micro-batch it re-solves the policy — by default the paper's (P1')

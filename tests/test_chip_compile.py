@@ -4,10 +4,11 @@ The TPU compiler is installed with jax; it compiles for a topology that is
 described, not attached, and refuses what the chip would refuse: VMEM
 blocks that do not fit, tiles that do not align, programs larger than HBM.
 Interpret mode on CPU can show none of that.  The aggregation cases
-compile ``fl_aggregate`` at the paper MLP's per-leaf sizes (784·200, 200,
-200·10, 10) for a row count R — the population K on the dense engine, the
-participant bucket on the sparse path, the micro-batch on the server — and
-assert the Pallas kernel is in the compiled program; the selective-scan
+compile ``fl_aggregate``, through ``fl.state.masked_aggregate``, at the
+paper MLP's per-leaf sizes (784·200, 200, 200·10, 10) for a row count R —
+the population K on the dense engine, the participant bucket on the sparse
+path, the micro-batch on the server — and assert the Pallas kernel is in
+the compiled program; the selective-scan
 cases compile its forward and backward kernels at Jamba2-3B's widths.
 
 The topology is described inside a module fixture, never at import: only
@@ -21,7 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import ops
+from repro.fl.state import masked_aggregate
 
 LEAF_SIZES = (784 * 200, 200, 200 * 10, 10)
 ROWS = (10, 64, 256, 1024, 10_000)
@@ -48,18 +49,20 @@ def one_chip():
 
 
 def _plain(g, d, w):
-    return ops.fl_aggregate(g, d, w, use_pallas=True)
+    return masked_aggregate(g, d, w, d.shape[0], use_pallas=True)
 
 
 def _subset(g, d, w):
-    return ops.fl_aggregate_subset(g, d, w, jnp.int32(1000), use_pallas=True)
+    return masked_aggregate(g, d, w, jnp.int32(1000), use_pallas=True)
 
 
-def _guarded(g, d, w):
-    return ops.fl_aggregate_guarded(g, d, w, use_pallas=True)
+def _weighted(g, d, w):
+    return masked_aggregate(g, d, w, 1, use_pallas=True)
 
 
-VARIANTS = {"plain": _plain, "subset": _subset, "guarded": _guarded}
+# the divisor as the kernel's static denom (dense engine), traced and folded
+# into the weights (sparse path, server), and 1 (guard and scheme weights)
+VARIANTS = {"plain": _plain, "subset": _subset, "weighted": _weighted}
 
 
 @pytest.mark.parametrize("variant", sorted(VARIANTS))

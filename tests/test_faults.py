@@ -22,8 +22,8 @@ from repro.fl.faults import (apply_faults, corrupt_deltas, init_fault_state,
                              markov_availability, scale_params,
                              uplink_process)
 from repro.fl.sparse import make_sparse_runner
-from repro.fl.state import (finite_rows, guard_weights, guarded_aggregate,
-                            masked_aggregate, update_norms)
+from repro.fl.state import (finite_rows, guard_weights, masked_aggregate,
+                            round_weights, update_norms)
 from repro.models.small import init_mlp, mlp_accuracy, mlp_loss
 
 DIM = 64
@@ -176,7 +176,9 @@ def test_guarded_aggregate_disabled_is_bitwise_plain():
     stale = jnp.zeros((4,), jnp.int32)
     plain = masked_aggregate(g, d, m, 4, use_pallas=False)
     for guards in (None, GuardConfig(quarantine=False)):
-        out = guarded_aggregate(g, d, m, 4, stale, guards, use_pallas=False)
+        w, denom, dd = round_weights(m, 4, stale, None, None, guards, d)
+        assert w is m and denom == 4 and dd is d
+        out = masked_aggregate(g, dd, w, denom, use_pallas=False)
         leaves_equal(out, plain)
 
 
@@ -187,8 +189,9 @@ def test_guarded_aggregate_rejects_poison_keeps_honest_mass():
     honest = jax.random.normal(jax.random.PRNGKey(1), (3, 5))
     d = {"w": jnp.concatenate([honest, jnp.full((1, 5), jnp.nan)], axis=0)}
     m = jnp.ones((4,))
-    out = guarded_aggregate(g, d, m, 4, jnp.zeros((4,), jnp.int32),
-                            GuardConfig(quarantine=True), use_pallas=False)
+    w, denom, d = round_weights(m, 4, jnp.zeros((4,), jnp.int32), None,
+                                None, GuardConfig(quarantine=True), d)
+    out = masked_aggregate(g, d, w, denom, use_pallas=False)
     want = {"w": jnp.sum(honest, axis=0) / 4.0}
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(want["w"]),
                                atol=1e-6)
@@ -387,10 +390,10 @@ def test_property_single_poison_row_never_passes_silently(bad_row, seed):
     m = jnp.ones((8,))
     unguarded = masked_aggregate({"w": g}, {"w": d}, m, 8, use_pallas=False)
     assert not all_finite(unguarded)
-    guarded = guarded_aggregate({"w": g}, {"w": d}, m, 8,
-                                jnp.zeros((8,), jnp.int32),
-                                GuardConfig(quarantine=True),
-                                use_pallas=False)
+    w, denom, dq = round_weights(m, 8, jnp.zeros((8,), jnp.int32), None,
+                                 None, GuardConfig(quarantine=True),
+                                 {"w": d})
+    guarded = masked_aggregate({"w": g}, dq, w, denom, use_pallas=False)
     assert all_finite(guarded)
     honest = jnp.sum(jnp.delete(d, bad_row, axis=0), axis=0) / 8.0
     np.testing.assert_allclose(np.asarray(guarded["w"]), np.asarray(honest),
@@ -409,10 +412,12 @@ def test_property_guarded_round_stays_finite(seed, p_corrupt):
     cfg = FaultConfig(p_corrupt=p_corrupt, corrupt_mode="nan")
     d = corrupt_deltas({"w": d}, flags, cfg.params(), cfg)
     m = (jax.random.uniform(k3, (6,)) < 0.7).astype(jnp.float32)
-    out = guarded_aggregate({"w": jnp.ones((12,))}, d, m, 6,
-                            jnp.zeros((6,), jnp.int32),
-                            GuardConfig(quarantine=True, clip_norm=5.0),
-                            use_pallas=False)
+    w, denom, d = round_weights(m, 6, jnp.zeros((6,), jnp.int32), None,
+                                None,
+                                GuardConfig(quarantine=True, clip_norm=5.0),
+                                d)
+    out = masked_aggregate({"w": jnp.ones((12,))}, d, w, denom,
+                           use_pallas=False)
     assert all_finite(out)
 
 

@@ -8,6 +8,8 @@ import numpy as np
 import pytest
 from _hypothesis_stub import given, settings, st
 
+from repro.fl.faults import GuardConfig
+from repro.fl.state import AggregatorConfig, masked_aggregate, round_weights
 from repro.kernels import ref
 from repro.kernels.fl_aggregate import fl_aggregate
 from repro.kernels.flash_attention import flash_attention
@@ -53,16 +55,28 @@ def test_fl_aggregate_property(K, M):
     np.testing.assert_allclose(np.asarray(out), 2.0, atol=1e-6)
 
 
+QUARANTINE = GuardConfig(quarantine=True)
+
+
+def _quarantined(g, d, w, use_pallas="interpret"):
+    """Quarantine through the one update: ``round_weights`` zeroes and
+    unweights the non-finite rows, ``masked_aggregate`` runs the kernel."""
+    w, denom, d = round_weights(w, 1, jnp.zeros(w.shape, jnp.int32), None,
+                                None, QUARANTINE, d)
+    return masked_aggregate(g, d, w, denom, use_pallas=use_pallas)
+
+
 @pytest.mark.parametrize("M", [128, 8193, 77])
 def test_fl_aggregate_guard_zeroes_nonfinite(M):
-    """guard=True quarantines NaN/Inf elements inside the kernel — the
-    result matches the sanitizing oracle and never goes non-finite."""
+    """Quarantine rejects the rows with NaN/Inf before the kernel — the
+    result is the oracle over the honest rows, and never goes non-finite."""
     g = jax.random.normal(jax.random.PRNGKey(0), (M,))
     d = jax.random.normal(jax.random.PRNGKey(1), (4, M))
     d = d.at[1].set(jnp.nan).at[2, 0].set(jnp.inf)
     w = jnp.array([0.25, 0.25, 0.0, 0.25])
-    out = fl_aggregate(g, d, w, interpret=True, denom=1, guard=True)
-    want = ref.fl_aggregate_guarded_ref(g, d, w)
+    out = _quarantined(g, d, w)
+    honest = jnp.array([0, 3])
+    want = ref.fl_aggregate_ref(g, d[honest], w[honest], 1)
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                **TOL[jnp.float32])
@@ -76,8 +90,10 @@ def test_fl_aggregate_guard_across_row_tiles():
     d = jax.random.normal(jax.random.PRNGKey(1), (R, M))
     d = d.at[140].set(jnp.nan).at[70, 5].set(-jnp.inf)
     w = jnp.full((R,), 1.0 / R).at[140].set(0.0).at[70].set(0.0)
-    out = fl_aggregate(g, d, w, interpret=True, denom=1, guard=True)
-    want = ref.fl_aggregate_guarded_ref(g, d, w)
+    out = _quarantined(g, d, w)
+    honest = np.setdiff1d(np.arange(R), [70, 140])
+    want = ref.fl_aggregate_ref(g, d[honest], w[honest], 1)
+    assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                **TOL[jnp.float32])
 
@@ -95,8 +111,45 @@ def test_fl_aggregate_guarded_ref_matches_manual():
     g = jnp.ones((5,))
     d = jnp.stack([jnp.full((5,), 2.0), jnp.full((5,), jnp.nan)])
     w = jnp.array([0.5, 0.5])
-    out = ref.fl_aggregate_guarded_ref(g, d, w)
-    np.testing.assert_allclose(np.asarray(out), 2.0)  # 1 + 0.5·2 + 0.5·0
+    out = _quarantined(g, d, w)
+    np.testing.assert_allclose(np.asarray(out), 2.0)  # 1 + 0.5·2 + 0·δ_1
+
+
+@pytest.mark.parametrize("divisor", ["static", "traced"])
+@pytest.mark.parametrize("rule", ["plain", "guarded", "scheme"])
+def test_masked_aggregate_kernel_matches_jnp(rule, divisor):
+    """Every update a round can apply — plain, guarded, scheme — with the
+    population as a Python int (dense engine: the kernel's static denom)
+    or traced (sparse path, server: folded into the weights): the
+    interpreted kernel matches the jnp path."""
+    R, K = 12, 40
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    g = {"w": jax.random.normal(ks[0], (9, 33)), "b": jnp.zeros((7,))}
+    d = {"w": jax.random.normal(ks[1], (R, 9, 33)),
+         "b": jax.random.normal(ks[2], (R, 7))}
+    mask = jax.random.uniform(ks[3], (R,)) < 0.6
+    stale = jnp.arange(R, dtype=jnp.int32) % 5
+    probs = jnp.linspace(0.1, 0.9, R)
+    agg = AggregatorConfig(kind="csmaafl", staleness_fn="poly") \
+        if rule == "scheme" else None
+    guards = GuardConfig(clip_norm=3.0, staleness_power=0.5) \
+        if rule == "guarded" else None
+
+    def update(num_clients, use_pallas):
+        w, denom, dd = round_weights(mask, num_clients, stale, probs, agg,
+                                     guards, d)
+        return masked_aggregate(g, dd, w, denom, use_pallas=use_pallas)
+
+    if divisor == "static":
+        jnp_out = update(K, False)
+        kernel_out = update(K, "interpret")
+    else:
+        jnp_out = jax.jit(lambda k: update(k, False))(jnp.int32(K))
+        kernel_out = jax.jit(lambda k: update(k, "interpret"))(jnp.int32(K))
+    for a, b in zip(jax.tree_util.tree_leaves(jnp_out),
+                    jax.tree_util.tree_leaves(kernel_out)):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   **TOL[jnp.float32])
 
 
 # ---------------------------------------------------------------------------

@@ -174,7 +174,7 @@ def test_staleness_scale_monotone_nonincreasing():
 def test_guarded_scheme_weights_stay_valid():
     # guards zero some rows; the normalized kinds renormalize over survivors
     from repro.fl.faults import GuardConfig
-    from repro.fl.state import guard_weights, scheme_aggregate
+    from repro.fl.state import masked_aggregate, round_weights
     rng = np.random.default_rng(5)
     D = 4
     g = jnp.zeros((D,))
@@ -183,10 +183,12 @@ def test_guarded_scheme_weights_stay_valid():
     mask = jnp.ones((K,), jnp.float32)
     stale = jnp.asarray(rng.integers(0, 6, K), jnp.int32)
     probs = jnp.asarray(rng.uniform(0.1, 1.0, K), jnp.float32)
-    out = scheme_aggregate(
-        g, deltas, mask, K, stale, probs,
+    w, denom, deltas = round_weights(
+        mask, K, stale, probs,
         AggregatorConfig(kind="fedasync", staleness_fn="poly"),
-        guards=GuardConfig(quarantine=True))
+        GuardConfig(quarantine=True), deltas)
+    assert denom == 1 and float(w[1]) == 0.0
+    out = masked_aggregate(g, deltas, w, denom)
     assert np.isfinite(np.asarray(out)).all()
 
 
