@@ -185,20 +185,15 @@ def update_ledger_taps(ms: MetricsState, spec: MetricsSpec, *,
 
 def _effective_weights(deltas, delivered, staleness, probs, num_clients,
                        guards, agg_params):
-    """Mirror of the engines' aggregation-weight choice (state.py): guard
-    weights fold into the delivery mask, then either the pluggable scheme
-    weights or the paper's m/K.  Recomputed here (a few row-vector ops) so
-    the aggregation functions keep their signatures and the untapped
-    program stays untouched."""
-    from ..fl.state import guard_weights, scheme_weights
+    """Each row's weight in the round's eq.-3 update: the weights over the
+    divisor that :func:`repro.fl.state.round_weights` hands the engines.
+    Recomputed here (a few row-vector ops) so the untapped program stays
+    untouched."""
+    from ..fl.state import round_weights
 
-    m = delivered.astype(jnp.float32)
-    if guards is not None and getattr(guards, "active", False):
-        gw, _ = guard_weights(deltas, staleness, guards)
-        m = m * gw
-    if agg_params is not None:
-        return scheme_weights(m, staleness, probs, agg_params, num_clients)
-    return m / jnp.asarray(num_clients, jnp.float32)
+    w, denom, _ = round_weights(delivered, num_clients, staleness, probs,
+                                agg_params, guards, deltas)
+    return w.astype(jnp.float32) / jnp.asarray(denom, jnp.float32)
 
 
 def update_train_taps(ms: MetricsState, spec: MetricsSpec, *,

@@ -56,13 +56,15 @@ def _subset(g, d, w):
     return masked_aggregate(g, d, w, jnp.int32(1000), use_pallas=True)
 
 
-def _weighted(g, d, w):
+def _guarded(g, d, w):
     return masked_aggregate(g, d, w, 1, use_pallas=True)
 
 
 # the divisor as the kernel's static denom (dense engine), traced and folded
-# into the weights (sparse path, server), and 1 (guard and scheme weights)
-VARIANTS = {"plain": _plain, "subset": _subset, "weighted": _weighted}
+# into the weights (sparse path, server), and 1 (weights that hold their own
+# scaling, as a scheme round passes them; the case keeps the name it had
+# when the kernel also sanitised non-finite deltas)
+VARIANTS = {"plain": _plain, "subset": _subset, "guarded": _guarded}
 
 
 @pytest.mark.parametrize("variant", sorted(VARIANTS))
